@@ -70,6 +70,8 @@ def main() -> None:
     ap.add_argument("--date", default=None,
                     help="ISO date stamped into BENCH_history entries")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    print(f"# compile cache: {enable_compile_cache()}")
     print("name,us_per_call,derived")
     for name, fn in SUITES.items():
         if args.only and args.only != name:

@@ -53,11 +53,19 @@ from repro.server.fleet import FleetServer
 from repro.server.zones import ZoneGrid, ZoneShardedStore
 
 
-def _build(cfg: dict, *, overlap: bool) -> ServingLoop:
+def build_serving_loop(cfg: dict, *, overlap: bool,
+                       donate: bool | None = False) -> ServingLoop:
+    """The seeded serving workload of ``cfg`` behind one ``ServingLoop``.
+
+    Optional keys: ``Pc`` client points per object (default P // 8),
+    ``zcap`` zone capacity, ``shards`` session shards per zone (a
+    ``MeshSessionTier`` when > 1).  ``donate`` is the session collect's
+    donation policy (None = backend-aware auto)."""
     kn = Knobs(server_capacity=cfg["cap"],
                client_capacity=max(cfg["budget"] * 2, 64),
                max_object_points_server=cfg["P"],
-               max_object_points_client=max(cfg["P"] // 8, 8),
+               max_object_points_client=cfg.get("Pc",
+                                                max(cfg["P"] // 8, 8)),
                min_obs_before_sync=1)
     store = synthetic_store(cfg["n_live"], cfg["cap"], cfg["E"], cfg["P"],
                             seed=7, centroid_low=(-7.0, 0.0, -7.0),
@@ -71,15 +79,17 @@ def _build(cfg: dict, *, overlap: bool) -> ServingLoop:
     # per-zone cluster indexes serve core.query's shard planning, which
     # the serving query path (flat sweep over the publish buffer) never
     # touches — keep them off so both arms measure the serving loop only.
-    # Session (collect) donation stays OFF in BOTH arms: dispatching a jit
-    # that donates a buffer blocks the host until that buffer's producer
-    # retires, so donated collects re-serialize the very chain the
-    # deferred tick_start/tick_finish pipeline exists to overlap.  Ingest
-    # donation is unaffected (ServingLoop's _apply_delta_donated donates a
-    # generation whose producer finished a full tick earlier).
+    # The benchmark keeps session (collect) donation OFF in BOTH arms:
+    # dispatching a jit that donates a buffer blocks the host until that
+    # buffer's producer retires, so donated collects re-serialize the very
+    # chain the deferred tick_start/tick_finish pipeline exists to
+    # overlap.  Ingest donation is unaffected (ServingLoop's
+    # _apply_delta2_donated donates a generation whose producer finished a
+    # full tick earlier).
     srv = FleetServer(knobs=kn, embed_dim=cfg["E"], n_clients=cfg["C"],
-                      grid=grid, budget=cfg["budget"], donate=False,
-                      index=False, zoned=zoned)
+                      grid=grid, budget=cfg["budget"], donate=donate,
+                      index=False, zoned=zoned,
+                      n_session_shards=cfg.get("shards", 1))
     lg = LoadGenerator(LoadSpec(n_clients=cfg["C"], n_ticks=cfg["ticks"],
                                 base_hz=cfg["base_hz"],
                                 burst_hz=cfg["burst_hz"]),
@@ -100,8 +110,8 @@ def _arm(cfg: dict, *, overlap: bool) -> tuple:
     # warmup run compiles this arm's jits (donated variants are distinct
     # executables) so the measured run times steady-state dispatch
     warm_cfg = dict(cfg, ticks=min(6, cfg["ticks"]))
-    _build(warm_cfg, overlap=overlap).run(warm_cfg["ticks"])
-    loop = _build(cfg, overlap=overlap)
+    build_serving_loop(warm_cfg, overlap=overlap).run(warm_cfg["ticks"])
+    loop = build_serving_loop(cfg, overlap=overlap)
     stats = loop.run(cfg["ticks"])
     return loop, stats
 

@@ -26,9 +26,11 @@ from repro.core import Knobs, MappingServer
 from repro.data.scenes import make_scene, scene_stream
 from repro.perception.embedder import OracleEmbedder
 from repro.server import FleetSimulator, Query, ZoneGrid
+from repro.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     n_clients = int(sys.argv[1]) if len(sys.argv) > 1 else 24
     n_ticks = 30
     kn = Knobs(server_capacity=256, client_capacity=64,

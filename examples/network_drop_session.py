@@ -30,9 +30,11 @@ from repro.perception.embedder import OracleEmbedder
 from repro.sim import (ClientSpec, NetTrace, ObjectEvent, PoseTrack,
                        QueryPlan, Scenario, ScenarioEngine)
 from repro.sim.scenario import GridSpec
+from repro.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     scene = make_scene(n_objects=25, seed=2)
     classes = {o.oid: o.class_id for o in scene.objects}
     emb = OracleEmbedder(embed_dim=256)

@@ -14,9 +14,11 @@ import numpy as np
 from repro.core import Knobs, MappingServer, Query, execute_query
 from repro.data.scenes import CLASS_NAMES, make_scene, scene_stream
 from repro.perception.embedder import OracleEmbedder
+from repro.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     scene = make_scene(n_objects=30, seed=0)
     classes = {o.oid: o.class_id for o in scene.objects}
     embedder = OracleEmbedder(embed_dim=256)

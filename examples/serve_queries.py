@@ -23,9 +23,11 @@ from repro.core import Knobs, MappingServer, Query
 from repro.data.scenes import make_scene, scene_stream
 from repro.perception.embedder import OracleEmbedder
 from repro.serving.batching import BatchScheduler, make_query_step_fn
+from repro.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     scene = make_scene(n_objects=30, seed=0)
     classes = {o.oid: o.class_id for o in scene.objects}
     emb = OracleEmbedder(embed_dim=256)

@@ -12,8 +12,10 @@ from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "src"))
 
+from repro.compile_cache import enable_compile_cache
 from repro.launch.train import main
 
 if __name__ == "__main__":
+    enable_compile_cache()
     args = sys.argv[1:] or ["--steps", "200", "--batch", "8", "--seq", "256"]
     main(["--arch", "semanticxr-captioner-110m"] + args)

@@ -17,9 +17,11 @@ import jax.numpy as jnp
 from repro.data.scenes import make_scene
 from repro.perception import clip as clip_mod
 from repro.optim import adamw
+from repro.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--batch", type=int, default=16)

@@ -41,8 +41,9 @@ def lift_depth(depth: jax.Array, mask: jax.Array, intrinsics: jax.Array,
     y = (ys_full - cy) / fy * z
     pts_cam = jnp.stack([x, y, z], axis=-1).reshape(-1, 3)
     valid = valid.reshape(-1)
-    # world = R @ p + t
-    pts_w = pts_cam @ pose[:3, :3].T + pose[:3, 3]
+    # world = R @ p + t, in full f32 (TPU's default matmul pass is bf16)
+    pts_w = jnp.matmul(pts_cam, pose[:3, :3].T,
+                       precision=jax.lax.Precision.HIGHEST) + pose[:3, 3]
     # deterministic top-max_points selection of valid pixels
     order = jnp.argsort(~valid)                     # valid first, stable
     take = order[:max_points]

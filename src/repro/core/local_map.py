@@ -57,7 +57,9 @@ def compute_priority(embed, label, centroid, *, user_pos, knobs: Knobs,
     prox = 1.0 / (1.0 + jnp.linalg.norm(centroid - user_pos, axis=-1))
     score = knobs.proximity_weight * prox
     if interest_embeds is not None and interest_embeds.shape[0] > 0:
-        sem = jnp.max(embed @ interest_embeds.T, axis=-1)
+        sem = jnp.max(jnp.matmul(embed, interest_embeds.T,
+                                 precision=jax.lax.Precision.HIGHEST),
+                      axis=-1)
         score = score + knobs.semantic_weight * jnp.maximum(sem, 0.0)
     if knobs.priority_classes:
         boost = jnp.isin(label, jnp.asarray(knobs.priority_classes,

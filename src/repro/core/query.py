@@ -285,7 +285,10 @@ def _execute(spec: Query, cols: _Cols, *, use_pallas: bool = False):
         scores, slots = kops.query_topk_bias(qs, cols.embed, bias, k)
     else:
         if spec.embed is not None:
-            sim = spec.embed @ cols.embed.T                # [Q, cap]
+            # fp32 contraction: TPU's default f32 matmul is one bf16
+            # pass (~1e-3 score error), which reorders near ties
+            sim = jnp.matmul(spec.embed, cols.embed.T,
+                             precision=jax.lax.Precision.HIGHEST)  # [Q, cap]
             if spec.sem_weight is not None:
                 sim = sim * spec.sem_weight[:, None]
         else:

@@ -174,7 +174,10 @@ def _stage1(spec, summ, *, m: int, use_pallas: bool, has_obs: bool,
         qs = spec.embed
         if spec.sem_weight is not None:
             qs = qs * spec.sem_weight[:, None]
-        sim = qs @ summ.embed_mean.T                       # [Q, M]
+        # fp32 contraction: a bf16-pass bound could undercut a member's
+        # exact score and void the certificate
+        sim = jnp.matmul(qs, summ.embed_mean.T,
+                         precision=jax.lax.Precision.HIGHEST)   # [Q, M]
         ub = jnp.where(bias > NEG * 0.5, sim + bias, NEG)
         if use_pallas and m <= _KERNEL_MAX_K:
             from repro.kernels import ops as kops
@@ -320,7 +323,8 @@ def _cluster_execute(spec, summ, *, has_obs: bool, has_seen: bool):
         qs = spec.embed
         if spec.sem_weight is not None:
             qs = qs * spec.sem_weight[:, None]
-        score = score + qs @ summ.embed_mean.T
+        score = score + jnp.matmul(qs, summ.embed_mean.T,
+                                   precision=jax.lax.Precision.HIGHEST)
     if spec.near is not None and spec.prox_weight is not None:
         center, _ = spec.near
         d = jnp.linalg.norm(summ.centroid[None] - center[:, None, :],

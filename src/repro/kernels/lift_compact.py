@@ -13,16 +13,16 @@ depth frame that serves all D detections at once:
   * back-projection is computed per pixel tile ONCE and shared across
     objects (the seed recomputed nothing per object either, but paid the
     [D, HW, 3] gather instead);
-  * per-object compaction uses cumsum/prefix-count destination indexing —
-    the r-th valid pixel of object d has rank r by construction, O(HW),
-    no sort of any kind;
+  * per-object compaction uses prefix-count destination indexing — the
+    r-th valid pixel of object d has rank r by construction, O(HW), no
+    sort of any kind;
   * the stride-downsample to the point budget is folded into the same
     indexing (rank r is kept iff some output slot i maps to it under
     ``floor(i * n / budget)`` — at most one i per rank since n >= budget
     makes the map strictly increasing), so ``downsample`` disappears as a
     separate dispatch;
-  * centroid / bbox accumulate over the selected points in the same sweep,
-    so association no longer needs a per-detection ``centroid_bbox`` pass.
+  * centroid / bbox are one reduction over the [D, budget, 3] output, so
+    association no longer needs a per-detection ``centroid_bbox`` pass.
 
 Output semantics are bit-for-bit those of the seed composition
 ``downsample(lift_depth(...), budget)`` + ``centroid_bbox`` (oracle:
@@ -35,11 +35,13 @@ not exist; all real clouds are identical.
 
 Two implementations of the same algorithm:
 
-  * ``lift_compact_pallas`` — the TPU deploy kernel.  Grid over pixel
-    tiles; the [D, P, 3] output refs act as cross-step carries (grids are
-    sequential on TPU); the per-tile scatter is a one-hot MXU matmul
-    ([P, T] @ [T, 3] per object), which Mosaic handles natively where a
-    per-element scatter would not.
+  * ``lift_compact_pallas`` — the TPU deploy kernel.  Grid over (output
+    slot block, pixel tile); the lane-dense [D, 8, P] output block is the
+    cross-step carry along the tile axis (grids are sequential on TPU).
+    Ranks come from a triangular-matmul prefix count, and the per-tile
+    scatter is a one-hot MXU matmul ([8, T] x [Pb, T]^T per object, fp32
+    contraction so every copied coordinate is exact), which Mosaic handles
+    natively where a per-element scatter would not.
   * ``lift_compact_xla`` — the algorithmically identical XLA formulation
     used off-TPU (ops.lift_compact keys off the backend): the one-hot
     matmul trick only pays for itself on the MXU; on CPU/GPU the rank
@@ -59,27 +61,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 BIG = 1e9
 Z_EPS = 1e-4          # matches geometry.lift_depth's valid-depth floor
-
-
-def _select_slots(rho, nl, budget: int, lift_cap: int):
-    """Map valid-pixel ranks to output slots under the fused downsample.
-
-    rho: [..., ] exclusive ranks (int32); nl: broadcastable capped counts.
-    Returns (slot, keep): rank rho is emitted to ``slot`` iff ``keep``.
-    Inverts downsample's ``idx(i) = floor(i * n / budget)``: the unique
-    candidate slot for rank r is ceil(r * budget / n), which wins iff it
-    maps back to r.  Below budget the map is the identity.
-    """
-    nl_safe = jnp.maximum(nl, 1)
-    in_range = rho < nl
-    # clip before the multiply: ranks >= nl are never kept, and the clip
-    # keeps rho * budget well inside int32 for any frame size
-    rho_c = jnp.minimum(rho, lift_cap)
-    over = nl > budget
-    slot = jnp.where(over, (rho_c * budget + nl_safe - 1) // nl_safe, rho_c)
-    hit = jnp.where(over, (slot * nl) // budget == rho_c, rho_c < budget)
-    keep = in_range & hit & (slot < budget)
-    return slot, keep
+BLOCK_P = 512         # output slots per kernel block (lane-dense, 4 x 128)
 
 
 # ----------------------------------------------------------------------
@@ -122,41 +104,88 @@ def lift_compact_xla(depth: jax.Array, masks: jax.Array,
     x = (xs_full - cx) / fx * zb
     y = (ys_full - cy) / fy * zb
     pts_cam = jnp.stack([x, y, zb], axis=-1)               # [D, budget, 3]
-    pts_w = pts_cam @ pose[:3, :3].T + pose[:3, 3]
+    pts_w = jnp.matmul(pts_cam, pose[:3, :3].T,
+                       precision=jax.lax.Precision.HIGHEST) + pose[:3, 3]
 
     valid = (i[None, :] < n_out[:, None])[..., None]
     pts = jnp.where(valid, pts_w, 0.0)
+    return (pts, n_out) + _cloud_stats(pts, n_out)
+
+
+def _cloud_stats(pts, n_out):
+    """Centroid and bbox of zero-padded [D, budget, 3] clouds holding
+    ``n_out`` points each (all zeros for an empty cloud)."""
+    valid = (jnp.arange(pts.shape[1])[None, :] < n_out[:, None])[..., None]
     denom = jnp.maximum(n_out, 1).astype(jnp.float32)[:, None]
     cent = jnp.sum(pts, axis=1) / denom
-    mn = jnp.min(jnp.where(valid, pts_w, BIG), axis=1)
-    mx = jnp.max(jnp.where(valid, pts_w, -BIG), axis=1)
+    mn = jnp.min(jnp.where(valid, pts, BIG), axis=1)
+    mx = jnp.max(jnp.where(valid, pts, -BIG), axis=1)
     nz = (n_out > 0)[:, None]
-    return (pts, n_out, cent,
-            jnp.where(nz, mn, 0.0), jnp.where(nz, mx, 0.0))
+    return cent, jnp.where(nz, mn, 0.0), jnp.where(nz, mx, 0.0)
 
 
 # ----------------------------------------------------------------------
 # Pallas streaming kernel (TPU deploy path)
 # ----------------------------------------------------------------------
 
-def _kernel(depth_ref, masks_ref, nl_ref, params_ref, pts_ref, csum_ref,
-            bmin_ref, bmax_ref, base_scr, *, W: int, stride: int,
-            block_t: int, budget: int, lift_cap: int):
-    step = pl.program_id(0)
+def _ceil_div(a, b):
+    """ceil(a / b) for int32 arrays with 0 <= a < 2**24 and b > 0.
 
-    @pl.when(step == 0)
+    Mosaic has no vector integer division, so the quotient is an f32
+    estimate (off by at most one: a and b are exact in f32) corrected one
+    step each way with exact integer products.  The same function serves
+    the kernel and the tile-span prologue, so both agree bit for bit."""
+    q = jnp.ceil(a.astype(jnp.float32) / b.astype(jnp.float32))
+    q = q.astype(jnp.int32)
+    q = jnp.where(q * b < a, q + 1, q)
+    return jnp.where((q - 1) * b >= a, q - 1, q)
+
+
+def _slot_of(rank, nl, budget: int, lift_cap: int):
+    """Output slot of valid-pixel ``rank`` under the fused downsample.
+
+    Inverts downsample's ``idx(i) = floor(i * n / budget)``: below the
+    budget the map is the identity; above it the unique candidate slot for
+    rank r is ceil(r * budget / n), which is kept iff it maps back to r
+    (see ``_kernel``).  ``lift_cap * budget < 2**24`` keeps the products
+    exact."""
+    rc = jnp.minimum(rank, lift_cap)
+    return jnp.where(nl > budget,
+                     _ceil_div(rc * budget, jnp.maximum(nl, 1)), rc)
+
+
+def _tile_spans(cnt, nl, budget: int, lift_cap: int):
+    """[D, n_t] valid-pixel counts per tile -> flat int32 [n_t * D * 2]
+    (lo, hi) slot bounds of what tile t can write for object d; an empty
+    span is (2**30, -1).  Slots are monotone in rank, so the kept slots of
+    the tile's ranks [base, base + cnt) lie in [slot(first), slot(last)]:
+    the kernel skips every (tile, object, slot block) the span misses."""
+    base = jnp.cumsum(cnt, axis=1) - cnt
+    last = jnp.minimum(base + cnt, nl) - 1
+    lo = _slot_of(base, nl, budget, lift_cap)
+    hi = jnp.minimum(_slot_of(last, nl, budget, lift_cap), budget - 1)
+    live = (last >= base) & (lo <= hi)
+    lo = jnp.where(live, lo, 1 << 30)
+    hi = jnp.where(live, hi, -1)
+    return jnp.stack([lo, hi], axis=-1).transpose(1, 0, 2).reshape(-1)
+
+
+def _kernel(span_ref, depth_ref, valid_ref, nl_ref, params_ref, out_ref,
+            base_scr, slot_scr, *, W: int, stride: int, block_t: int,
+            block_p: int, budget: int, lift_cap: int, n_obj: int):
+    pb = pl.program_id(0)              # output slot block
+    t = pl.program_id(1)               # pixel tile
+
+    @pl.when(t == 0)
     def _init():
-        pts_ref[...] = jnp.zeros_like(pts_ref)
-        csum_ref[...] = jnp.zeros_like(csum_ref)
-        bmin_ref[...] = jnp.full_like(bmin_ref, BIG)
-        bmax_ref[...] = jnp.full_like(bmax_ref, -BIG)
+        out_ref[...] = jnp.zeros_like(out_ref)
         base_scr[...] = jnp.zeros_like(base_scr)
 
     # --- shared back-projection: once per tile, for ALL objects
     z = depth_ref[...]                                     # [1, T]
     fx, fy, cx, cy = (params_ref[0], params_ref[1], params_ref[2],
                       params_ref[3])
-    j = step * block_t + jax.lax.broadcasted_iota(jnp.int32, (1, block_t), 1)
+    j = t * block_t + jax.lax.broadcasted_iota(jnp.int32, (1, block_t), 1)
     row = j // W
     xs_full = ((j - row * W).astype(jnp.float32) + 0.5) * stride
     ys_full = (row.astype(jnp.float32) + 0.5) * stride
@@ -168,30 +197,55 @@ def _kernel(depth_ref, masks_ref, nl_ref, params_ref, pts_ref, csum_ref,
         params_ref[14]
     wz = params_ref[10] * x + params_ref[11] * y + params_ref[12] * z + \
         params_ref[15]
-    w = jnp.concatenate([wx, wy, wz], axis=0).T            # [T, 3]
+    r8 = jax.lax.broadcasted_iota(jnp.int32, (8, block_t), 0)
+    w8 = jnp.where(r8 == 0, wx, jnp.where(r8 == 1, wy,
+                                          jnp.where(r8 == 2, wz, 0.0)))
 
-    # --- per-object prefix-count destination indexing
-    vi = jnp.where(masks_ref[...] > 0, (z > Z_EPS).astype(jnp.int32), 0)
-    rho = base_scr[...] + jnp.cumsum(vi, axis=1) - vi      # exclusive [D, T]
-    base_scr[...] = base_scr[...] + jnp.sum(vi, axis=1, keepdims=True)
-    slot, keep = _select_slots(rho, nl_ref[...], budget, lift_cap)
-    sel = keep & (vi > 0)
+    # --- per-object exclusive prefix count: a strictly-upper-triangular
+    # [T, T] matmul.  0/1 operands are exact in any MXU pass and the f32
+    # accumulator holds counts <= T exactly.
+    vi = valid_ref[...]                                    # [D, T] 0/1 f32
+    ii = jax.lax.broadcasted_iota(jnp.int32, (block_t, block_t), 0)
+    jj = jax.lax.broadcasted_iota(jnp.int32, (block_t, block_t), 1)
+    upper = jnp.where(ii < jj, 1.0, 0.0)
+    excl = jnp.dot(vi, upper, preferred_element_type=jnp.float32)
+    base = base_scr[...]                                   # [D, 1]
+    rho = base + excl.astype(jnp.int32)
+    base_scr[...] = base + jnp.sum(vi, axis=1,
+                                   keepdims=True).astype(jnp.int32)
 
-    # --- one-hot MXU scatter: each kept pixel owns exactly one slot, so
-    # the accumulated value is the exact point (0 everywhere else)
-    slots = jax.lax.broadcasted_iota(jnp.int32, (1, 1, budget), 2)
-    oh = (jnp.where(sel, slot, -1)[:, :, None] == slots)   # [D, T, P]
-    pts_ref[...] += jnp.einsum("dtp,tc->dpc", oh.astype(jnp.float32), w,
-                               preferred_element_type=jnp.float32)
+    # --- destination slot per pixel (-1 = not emitted), int32 throughout:
+    # rank rho is kept iff it is a valid pixel, inside the lift cap, and
+    # its candidate slot maps back to it (slot * n - rho * budget < budget)
+    nlb = nl_ref[...] + jnp.zeros_like(rho)                # [D, T]
+    slot = _slot_of(rho, nlb, budget, lift_cap)
+    miss = jnp.where(nlb > budget,
+                     slot * nlb - jnp.minimum(rho, lift_cap) * budget, 0)
+    slot_scr[...] = jnp.where(
+        vi > 0, jnp.where(rho < nlb, jnp.where(
+            miss < budget, jnp.where(slot < budget, slot, -1), -1), -1), -1)
 
-    # --- centroid / bbox folded into the same sweep
-    sel3 = sel[:, :, None]
-    wb = w[None, :, :]                                     # [1, T, 3]
-    csum_ref[...] += jnp.sum(jnp.where(sel3, wb, 0.0), axis=1)
-    bmin_ref[...] = jnp.minimum(bmin_ref[...],
-                                jnp.min(jnp.where(sel3, wb, BIG), axis=1))
-    bmax_ref[...] = jnp.maximum(bmax_ref[...],
-                                jnp.max(jnp.where(sel3, wb, -BIG), axis=1))
+    # --- one-hot MXU scatter per object into this slot block: each kept
+    # pixel owns exactly one slot, so with fp32 contraction the
+    # accumulated value is the exact point (0 everywhere else)
+    p_ids = pb * block_p + jax.lax.broadcasted_iota(
+        jnp.int32, (block_p, block_t), 0)
+
+    def scatter(d, carry):
+        k = (t * n_obj + d) * 2
+        hit = (span_ref[k + 1] >= pb * block_p) & \
+            (span_ref[k] < (pb + 1) * block_p)
+
+        @pl.when(hit)
+        def _():
+            oh = jnp.where(p_ids == slot_scr[pl.ds(d, 1), :], 1.0, 0.0)
+            out_ref[d] += jax.lax.dot_general(
+                w8, oh, (((1,), (1,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)        # [8, Pb]
+        return carry
+
+    jax.lax.fori_loop(0, n_obj, scatter, 0)
 
 
 def lift_compact_pallas(depth: jax.Array, masks: jax.Array,
@@ -200,59 +254,70 @@ def lift_compact_pallas(depth: jax.Array, masks: jax.Array,
                         block_t: int = 512, interpret: bool | None = None):
     """Streaming-kernel variant of ``lift_compact_xla`` (same contract).
 
-    The depth tile stream is the only HW-sized traffic: depth + masks pass
-    through VMEM once, outputs are [D, budget, 3] + [D, 3] stats.  The
-    per-object valid-pixel counts (needed up front by the fused downsample
-    indexing) come from one cheap masked reduction outside the kernel.
+    Grid ``(slot blocks, pixel tiles)``.  The depth + valid-mask stream is
+    the only HW-sized traffic (re-read once per slot block); the points
+    leave as a lane-dense ``[D, 8, P_pad]`` slab (rows x, y, z, then zero
+    padding up to the 8-sublane tile) that the wrapper slices to
+    ``[D, budget, 3]``.  Per-tile valid counts, the lift-capped object
+    counts and each (tile, object) slot span come from one cheap reduction
+    outside the kernel; the spans ride in SMEM (scalar prefetch) so a
+    tile skips the objects it holds no kept pixel of.  Centroid and bbox
+    are a reduction over the ``[D, budget, 3]`` output, as in the XLA
+    formulation.
+
+    VMEM at the knob defaults (D=32, block_t=512, 512-slot blocks): double-
+    buffered blocks = valid 2 * 64 KiB + depth 2 * 16 KiB + points
+    2 * 512 KiB; scratch = slots 64 KiB + counts 16 KiB; per-step values =
+    the [T, T] triangle and its iotas 3 MiB, the [Pb, T] slot ids and
+    one-hot 2 MiB, the fp32-contraction splits of the one-hot about 2 MiB.
+    About 9 MiB in all, inside v5e's 16 MiB default scoped limit, so no
+    ``vmem_limit_bytes`` is set.
     """
     if interpret is None:
         from repro.kernels.ops import _interpret
         interpret = _interpret()
+    assert lift_cap * budget < 1 << 24, "slot arithmetic must stay exact"
     D, H, W = masks.shape
     HW = H * W
     z_flat = depth.reshape(1, HW)
-    m_flat = masks.reshape(D, HW)
-    counts = jnp.sum(m_flat & (z_flat > Z_EPS), axis=1).astype(jnp.int32)
-    nl = jnp.minimum(counts, lift_cap)[:, None]            # [D, 1]
-    n_out = jnp.minimum(nl[:, 0], budget)
-
+    v = (masks.reshape(D, HW) & (z_flat > Z_EPS)).astype(jnp.int32)
     pad = (-HW) % block_t
     if pad:
         z_flat = jnp.pad(z_flat, ((0, 0), (0, pad)))
-        m_flat = jnp.pad(m_flat, ((0, 0), (0, pad)))
+        v = jnp.pad(v, ((0, 0), (0, pad)))
+    n_t = (HW + pad) // block_t
+    cnt = v.reshape(D, n_t, block_t).sum(axis=2)           # [D, n_t]
+    nl = jnp.minimum(cnt.sum(axis=1), lift_cap)[:, None]   # [D, 1]
+    n_out = jnp.minimum(nl[:, 0], budget)
+    spans = _tile_spans(cnt, nl, budget, lift_cap)
+
+    block_p = min(BLOCK_P, -(-budget // 128) * 128)
+    p_pad = -(-budget // block_p) * block_p
     params = jnp.concatenate([
         jnp.asarray(intrinsics, jnp.float32).reshape(4),
         jnp.asarray(pose, jnp.float32)[:3, :3].reshape(9),
         jnp.asarray(pose, jnp.float32)[:3, 3].reshape(3),
     ])
-    grid = ((HW + pad) // block_t,)
-    pts, csum, bmin, bmax = pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_kernel, W=W, stride=stride, block_t=block_t,
-                          budget=budget, lift_cap=lift_cap),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_t), lambda i: (0, i)),   # depth stream
-            pl.BlockSpec((D, block_t), lambda i: (0, i)),   # mask stream
-            pl.BlockSpec((D, 1), lambda i: (0, 0)),         # counts resident
-            pl.BlockSpec(memory_space=pltpu.SMEM),          # intr + pose
-        ],
-        out_specs=[
-            pl.BlockSpec((D, budget, 3), lambda i: (0, 0, 0)),
-            pl.BlockSpec((D, 3), lambda i: (0, 0)),
-            pl.BlockSpec((D, 3), lambda i: (0, 0)),
-            pl.BlockSpec((D, 3), lambda i: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((D, budget, 3), jnp.float32),
-            jax.ShapeDtypeStruct((D, 3), jnp.float32),
-            jax.ShapeDtypeStruct((D, 3), jnp.float32),
-            jax.ShapeDtypeStruct((D, 3), jnp.float32),
-        ],
-        scratch_shapes=[pltpu.VMEM((D, 1), jnp.int32)],
+                          block_p=block_p, budget=budget, lift_cap=lift_cap,
+                          n_obj=D),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(p_pad // block_p, n_t),
+            in_specs=[
+                pl.BlockSpec((1, block_t), lambda p, t, s: (0, t)),
+                pl.BlockSpec((D, block_t), lambda p, t, s: (0, t)),
+                pl.BlockSpec((D, 1), lambda p, t, s: (0, 0)),
+                pl.BlockSpec(memory_space=pltpu.SMEM),     # intr + pose
+            ],
+            out_specs=pl.BlockSpec((D, 8, block_p), lambda p, t, s: (0, 0, p)),
+            scratch_shapes=[pltpu.VMEM((D, 1), jnp.int32),
+                            pltpu.VMEM((D, block_t), jnp.int32)]),
+        out_shape=jax.ShapeDtypeStruct((D, 8, p_pad), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-    )(z_flat, m_flat.astype(jnp.int32), nl, params)
-
-    denom = jnp.maximum(n_out, 1).astype(jnp.float32)[:, None]
-    nz = (n_out > 0)[:, None]
-    return (pts, n_out.astype(jnp.int32), csum / denom,
-            jnp.where(nz, bmin, 0.0), jnp.where(nz, bmax, 0.0))
+    )(spans, z_flat, v.astype(jnp.float32), nl, params)
+    pts = out[:, :3, :budget].transpose(0, 2, 1)           # [D, budget, 3]
+    return (pts, n_out.astype(jnp.int32)) + _cloud_stats(pts, n_out)

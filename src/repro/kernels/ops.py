@@ -1,8 +1,12 @@
 """jit'd public wrappers over the Pallas kernels.
 
-On this CPU container kernels execute in interpret mode (the Python kernel
-body runs per grid step); on TPU the same calls compile to Mosaic.  The
-``interpret`` default keys off the backend so the code is deploy-ready.
+The backend picks the mode: on a TPU every kernel compiles to Mosaic; on
+any other backend it runs in Pallas interpret mode (the kernel body as
+XLA ops, one grid step at a time), which is how the tests exercise the
+kernels with ``JAX_PLATFORMS=cpu``.  ``python chip_smoke.py [--chips 4]``
+runs the served path on the chip and fails where JAX finds no TPU.  Entry
+points keep JAX's compile cache in ``$JAX_COMPILATION_CACHE_DIR`` when set,
+else in ``<repo>/.jax_cache`` (``repro.compile_cache``).
 """
 from __future__ import annotations
 

@@ -18,8 +18,9 @@ traffic, small next to the table's O(N*E) — so a predicate-heavy query
 stays within a few percent of the embedding-only dispatch and never pays a
 gather/compaction pass over the table.
 
-The block fold is a proper top-k merge: top-k of the block (sort-based,
-O(Nb log Nb) work on the VPU) then a [2k] merge with the running list.
+The block fold is a proper top-k merge: k rounds of max-and-mask over the
+running list and the block (O(k * Nb) work on the VPU, k <= 16 in
+practice), with ``lax.top_k``'s tie order.
 
 The same kernel shape serves BOTH levels of the hierarchical query plan
 (repro.index.search): stage 1 streams the [M, E] cluster-summary mean
@@ -52,15 +53,34 @@ def _merge_topk(run_v, run_i, sim, base, k: int):
     """Fold one block's scores into the running (vals, idx) top-k lists.
 
     run_v/run_i: [Q, k] running top-k; sim: [Q, Nb] block scores.
-    Proper merge: block top-k, then top-k of the [2k] concatenation.
+    Exactly ``lax.top_k`` of the concatenation [running, block] — ties go
+    to the earlier position, so to the lower global index — as k rounds of
+    max-and-mask (Mosaic lowers no sort or top_k).  Each round takes the
+    row max, picks its first position (the running list precedes the
+    block), and retires that position with -inf, which no real candidate
+    holds (masked slots score NEG).
     """
-    bv, bloc = jax.lax.top_k(sim, k)                       # [Q, k]
-    bi = base + bloc.astype(jnp.int32)
-    cand_v = jnp.concatenate([run_v, bv], axis=1)          # [Q, 2k]
-    cand_i = jnp.concatenate([run_i, bi], axis=1)
-    mv, sel = jax.lax.top_k(cand_v, k)
-    mi = jnp.take_along_axis(cand_i, sel, axis=1)
-    return mv, mi
+    Q, nb = sim.shape
+    big = jnp.int32(2 ** 30)
+    lane_k = jax.lax.broadcasted_iota(jnp.int32, (Q, k), 1)
+    lane_b = jax.lax.broadcasted_iota(jnp.int32, (Q, nb), 1)
+    cv, bv = run_v, sim
+    out_v = jnp.full((Q, k), NEG, jnp.float32)
+    out_i = jnp.full((Q, k), -1, jnp.int32)
+    for r in range(k):
+        m = jnp.maximum(jnp.max(cv, axis=1, keepdims=True),
+                        jnp.max(bv, axis=1, keepdims=True))        # [Q, 1]
+        pc = jnp.min(jnp.where(cv == m, lane_k, big), axis=1, keepdims=True)
+        pb = jnp.min(jnp.where(bv == m, lane_b, big), axis=1, keepdims=True)
+        in_run = pc < big
+        ic = jnp.max(jnp.where(lane_k == pc, run_i, -1), axis=1,
+                     keepdims=True)
+        idx = jnp.where(in_run, ic, base + pb)
+        out_v = jnp.where(lane_k == r, m, out_v)
+        out_i = jnp.where(lane_k == r, idx, out_i)
+        cv = jnp.where(lane_k == pc, -jnp.inf, cv)
+        bv = jnp.where((lane_b == pb) & ~in_run, -jnp.inf, bv)
+    return out_v, out_i
 
 
 def query_topk_pallas(q: jax.Array, embeds: jax.Array, active: jax.Array,
@@ -83,9 +103,12 @@ def _bias_kernel(q_ref, e_ref, b_ref, vals_ref, idx_ref, *, k: int,
         vals_ref[...] = jnp.full_like(vals_ref, NEG)
         idx_ref[...] = jnp.full_like(idx_ref, -1)
 
-    # [Q, E] @ [E, Nb] -> [Q, Nb] on the MXU — one matmul serves all queries
-    sim = jnp.dot(q_ref[...], e_ref[...].T,
-                  preferred_element_type=jnp.float32)          # [Q, Nb]
+    # [Q, E] x [Nb, E]^T -> [Q, Nb] on the MXU — one matmul serves all
+    # queries; fp32 contraction, like the jnp engine path
+    sim = jax.lax.dot_general(q_ref[...], e_ref[...],
+                              (((1,), (1,)), ((), ())),
+                              precision=jax.lax.Precision.HIGHEST,
+                              preferred_element_type=jnp.float32)  # [Q, Nb]
     b = b_ref[...]                                             # [Q, Nb]
     # bias == NEG marks a predicate-excluded slot; finite bias is additive
     sim = jnp.where(b > NEG * 0.5, sim + b, NEG)

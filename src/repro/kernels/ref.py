@@ -1,14 +1,20 @@
-"""Pure-jnp oracles for every Pallas kernel (the allclose targets)."""
+"""Pure-jnp oracles for every Pallas kernel (the allclose targets).
+
+Score matmuls contract in full f32 (``HIGHEST``) so an oracle evaluated on
+the TPU, whose default f32 matmul is a single bf16 pass, stays an f32
+reference for the kernels (which contract in f32 too)."""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def query_topk_ref(q: jax.Array, embeds: jax.Array, active: jax.Array,
                    k: int):
     """q: [E]; embeds: [N, E]; active: [N] bool -> (scores [k], idx [k])."""
-    sim = embeds @ q
+    sim = jnp.matmul(embeds, q, precision=HIGHEST)
     sim = jnp.where(active, sim, -jnp.inf)
     return jax.lax.top_k(sim, k)
 
@@ -23,7 +29,7 @@ def query_topk_bias_ref(qs: jax.Array, embeds: jax.Array, bias: jax.Array,
                         k: int, *, neg: float = -1e30):
     """qs: [Q, E]; embeds: [N, E]; bias: [Q, N] -> ([Q, k], [Q, k]).
     bias == neg masks the slot out; finite bias is additive."""
-    sim = qs @ embeds.T
+    sim = jnp.matmul(qs, embeds.T, precision=HIGHEST)
     sim = jnp.where(bias > neg * 0.5, sim + bias, -jnp.inf)
     return jax.lax.top_k(sim, k)
 

@@ -15,9 +15,9 @@ jitted scatter per dirty zone, not per object).  Slot bookkeeping is
 host-side; freed shard slots are reported so the per-zone SessionManager
 can forget stale sync versions before the slot is reused.
 
-When a device mesh is available the shards are placed round-robin on its
-devices via `distributed.sharding.zone_shard_devices`; on the single-device
-container placement is a no-op.
+``place_on(mesh)`` places the shards round-robin on a mesh's devices
+(`distributed.sharding.zone_shard_devices`); refreshes then gather the
+changed rows on the global store's device and ship only those.
 """
 from __future__ import annotations
 
@@ -120,36 +120,47 @@ class ZoneGrid:
 
 
 @jax.jit
-def _zone_scatter(zone: ObjectStore, src: ObjectStore, g_idx: jax.Array,
-                  z_idx: jax.Array, valid: jax.Array, deact_idx: jax.Array,
+def _gather_rows(src: ObjectStore, g_idx: jax.Array) -> ObjectStore:
+    """Rows ``g_idx`` of the global store, live/tombstone state included —
+    all a zone shard needs of it, so only these rows cross to a shard
+    placed on another device."""
+    rows = src._replace(deleted=deleted_mask(src),
+                        next_id=jnp.zeros((), jnp.int32))
+    return jax.tree.map(lambda x: x[g_idx] if x.ndim else x, rows)
+
+
+@jax.jit
+def _zone_scatter(zone: ObjectStore, rows: ObjectStore, z_idx: jax.Array,
+                  valid: jax.Array, deact_idx: jax.Array,
                   deact_valid: jax.Array) -> ObjectStore:
-    """Copy src rows g_idx into zone rows z_idx and deactivate deact_idx —
-    one scatter per field, padding rows dropped via OOB indices."""
+    """Write gathered ``rows`` into zone rows z_idx and deactivate
+    deact_idx — one scatter per field, padding rows dropped via OOB
+    indices."""
     capz = zone.ids.shape[0]
     tgt = jnp.where(valid, z_idx, capz)
     dt = jnp.where(deact_valid, deact_idx, capz)
 
-    def put(zf, sf):
-        return zf.at[tgt].set(sf[g_idx], mode="drop")
+    def put(zf, rf):
+        return zf.at[tgt].set(rf, mode="drop")
 
     # copied rows take the SOURCE row's live/tombstone state (a global
     # tombstone mirrors as a shard tombstone so the deletion propagates
     # through the per-zone sync sessions); freed slots clear both
     active = zone.active.at[dt].set(False, mode="drop") \
-                        .at[tgt].set(src.active[g_idx], mode="drop")
+                        .at[tgt].set(rows.active, mode="drop")
     deleted = deleted_mask(zone).at[dt].set(False, mode="drop") \
-        .at[tgt].set(deleted_mask(src)[g_idx], mode="drop")
+        .at[tgt].set(rows.deleted, mode="drop")
     return ObjectStore(
-        ids=put(zone.ids, src.ids), active=active,
-        embed=put(zone.embed, src.embed), label=put(zone.label, src.label),
-        points=put(zone.points, src.points),
-        n_points=put(zone.n_points, src.n_points),
-        centroid=put(zone.centroid, src.centroid),
-        bbox_min=put(zone.bbox_min, src.bbox_min),
-        bbox_max=put(zone.bbox_max, src.bbox_max),
-        obs_count=put(zone.obs_count, src.obs_count),
-        version=put(zone.version, src.version),
-        last_seen=put(zone.last_seen, src.last_seen),
+        ids=put(zone.ids, rows.ids), active=active,
+        embed=put(zone.embed, rows.embed), label=put(zone.label, rows.label),
+        points=put(zone.points, rows.points),
+        n_points=put(zone.n_points, rows.n_points),
+        centroid=put(zone.centroid, rows.centroid),
+        bbox_min=put(zone.bbox_min, rows.bbox_min),
+        bbox_max=put(zone.bbox_max, rows.bbox_max),
+        obs_count=put(zone.obs_count, rows.obs_count),
+        version=put(zone.version, rows.version),
+        last_seen=put(zone.last_seen, rows.last_seen),
         next_id=zone.next_id, deleted=deleted)
 
 
@@ -168,6 +179,8 @@ class ZoneShardedStore:
     zone_capacity: int = 0
     max_points: int = 0
     zones: list = field(default_factory=list)
+    devices: list = None               # per-zone jax device (place_on);
+    #                                    None = the default device
     indexes: dict = field(default_factory=dict)  # zone -> ClusterIndex
     #                                  (enable_index; core.query discovers
     #                                   this attr for the two-stage plan)
@@ -256,8 +269,11 @@ class ZoneShardedStore:
                 gb, gv = _pad_idx(g_list, B)
                 sb, _ = _pad_idx(s_list, B)
                 db, dv = _pad_idx(freed, _bucket(max(len(freed), 1)))
-                self.zones[z] = _zone_scatter(self.zones[z], store, gb, sb,
-                                              gv, db, dv)
+                rows = _gather_rows(store, gb)
+                if self.devices is not None:
+                    rows = jax.device_put(rows, self.devices[z])
+                self.zones[z] = _zone_scatter(self.zones[z], rows, sb, gv,
+                                              db, dv)
                 # cluster-index maintenance rides the same delta: exactly
                 # the scattered + freed shard slots are re-indexed
                 zidx = self.indexes.get(z)
@@ -310,8 +326,9 @@ class ZoneShardedStore:
         return int(sum(int(np.asarray(z.active).sum()) for z in self.zones))
 
     def place_on(self, mesh) -> None:
-        """Place shard z on mesh device z % ndev (no-op on 1 device)."""
+        """Place shard z on mesh device z % ndev; later refreshes ship
+        only the changed rows to it (``_gather_rows``)."""
         from repro.distributed.sharding import zone_shard_devices
-        devs = zone_shard_devices(mesh, len(self.zones))
+        self.devices = zone_shard_devices(mesh, len(self.zones))
         self.zones = [jax.device_put(zone, d)
-                      for zone, d in zip(self.zones, devs)]
+                      for zone, d in zip(self.zones, self.devices)]

@@ -22,6 +22,27 @@ def test_query_topk(n, e, k):
     assert np.all(np.asarray(active)[np.asarray(si)]), "picked inactive slot"
 
 
+@pytest.mark.parametrize("n,k,block_n", [(3000, 5, 1024), (700, 16, 128)])
+def test_query_topk_bias_ties_match_ref(n, k, block_n):
+    """Exact score ties inside and across blocks resolve like the oracle's
+    ``lax.top_k``: lower slot first, ids included."""
+    from repro.kernels import query_topk as qt
+    rng = np.random.default_rng(n)
+    # small-integer rows and queries: every score is exact in f32 whatever
+    # the summation order, so equal rows tie bit for bit in both paths
+    base = rng.integers(-2, 3, size=(40, 64)).astype(np.float32)
+    embeds = jnp.asarray(base[rng.integers(0, 40, size=n)])   # many dupes
+    qs = jnp.asarray(rng.integers(-2, 3, size=(6, 64)).astype(np.float32))
+    bias = np.zeros((6, n), np.float32)
+    bias[:, rng.random(n) < 0.3] = qt.NEG                     # masked slots
+    bias[1] = np.round(rng.normal(size=n), 1)                 # tied bonuses
+    sv, si = qt.query_topk_bias_pallas(qs, embeds, jnp.asarray(bias), k,
+                                       block_n=block_n, interpret=True)
+    rv, ri = ref.query_topk_bias_ref(qs, embeds, jnp.asarray(bias), k)
+    np.testing.assert_array_equal(np.asarray(si), np.asarray(ri))
+    np.testing.assert_allclose(np.asarray(sv), np.asarray(rv), rtol=1e-6)
+
+
 @pytest.mark.parametrize("d,h,w,stride,budget,cap,block_t", [
     (4, 24, 32, 1, 64, 4096, 256),
     (8, 48, 64, 5, 512, 4096, 512),
