@@ -352,17 +352,6 @@ def _select_shards(spec: Query, target) -> list:
     return list(range(Z))
 
 
-def _count_flat_fallback():
-    """Mark an index-carrying target served by the flat sweep (below the
-    engagement threshold) — the coverage counterpart of
-    ``query_index_two_stage_total``."""
-    from repro.obs import metrics as obs_metrics
-    reg = obs_metrics.get_registry()
-    if reg is not None:
-        reg.counter("query_index_flat_total",
-                    "index present but below min_flat_size: flat sweep").inc()
-
-
 @dataclass
 class CompiledQuery:
     """A (spec, target)-shaped executable plan.
@@ -405,12 +394,10 @@ class CompiledQuery:
                         "target.cluster_index")
                 from repro.index.search import cluster_query
                 return cluster_query(spec, [(None, idx, target)])
-            if idx is not None:
-                if idx.engaged():
-                    from repro.index.search import two_stage_query
-                    return two_stage_query(spec, target, idx,
-                                           use_pallas=self.use_pallas)
-                _count_flat_fallback()
+            if idx is not None and idx.engaged():
+                from repro.index.search import two_stage_query
+                return two_stage_query(spec, target, idx,
+                                       use_pallas=self.use_pallas)
             return _execute(spec, _columns(target),
                             use_pallas=self.use_pallas)
         return self._run_sharded(target, spec)
@@ -464,8 +451,6 @@ class CompiledQuery:
                 parts.append(two_stage_query(bspec, zt, zidx,
                                              use_pallas=self.use_pallas))
             else:
-                if zidx is not None:
-                    _count_flat_fallback()
                 parts.append(_execute(bspec, _columns(zt),
                                       use_pallas=self.use_pallas))
         res = _merge_shards(jnp.stack([p.oids for p in parts]),

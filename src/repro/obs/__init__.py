@@ -4,7 +4,9 @@ Three pieces, one contract — *observation never perturbs the replay*:
 
 * ``obs.trace``     — near-zero-overhead span tracer (context manager +
                       decorator, nested spans, optional JAX fencing) with
-                      Chrome/Perfetto trace-event JSON export.
+                      Chrome/Perfetto trace-event JSON export; each span
+                      is also a ``jax.profiler`` annotation carrying its
+                      args.
 * ``obs.metrics``   — process-wide registry of counters / gauges /
                       fixed-bucket histograms with deterministic
                       percentile math and Prometheus-text / JSON export.

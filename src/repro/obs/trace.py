@@ -19,6 +19,16 @@ Design constraints (the reasons this file is small and boring):
   measures the real device cost, not the dispatch enqueue.  Fencing is
   opt-in because the extra syncs serialize work that would otherwise
   overlap (it trades wall-clock overhead for attribution honesty).
+* **On the profiler's clock too.**  Each span also opens a
+  ``jax.profiler.TraceAnnotation`` of its name for its extent and hands
+  it the span's args at exit, so a ``jax.profiler`` trace shows the
+  program's spans, with their args as event stats, on its host plane
+  beside the device ops.  Outside a profiling session the annotation is
+  a no-op.
+* **Args link the spans.**  Within a tick, the span that caused another
+  contains it; across ticks, spans link by id in their args (request ids
+  ``rids``, a collect's ``issue_tick``).  Call sites compute args only
+  when ``Span.on`` is true, so tracing off computes nothing.
 
 Export is the Chrome trace-event JSON format (chrome://tracing, Perfetto
 UI): complete events (``"ph": "X"``) with microsecond timestamps; nesting
@@ -31,12 +41,15 @@ import json
 import time
 from dataclasses import dataclass, field
 
+from jax.profiler import TraceAnnotation
+
 __all__ = ["Span", "Tracer", "get_tracer", "set_tracer", "span", "traced"]
 
 
 class _NullSpan:
     """Shared no-op span: the disabled-path cost is one ``is None`` test."""
     __slots__ = ()
+    on = False          # call sites compute span args only when on
 
     def __enter__(self):
         return self
@@ -64,10 +77,14 @@ class Span:
     tid: int = 0
     args: dict = None
     _fence: object = None
+    _ann: TraceAnnotation = None
+    on = True
 
     def __enter__(self):
         self.tid = self.tracer._depth
         self.tracer._depth += 1
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
         self.t0 = time.perf_counter()
         return self
 
@@ -88,6 +105,9 @@ class Span:
             import jax
             jax.block_until_ready(self._fence)
         t1 = time.perf_counter()
+        if self.args:
+            self._ann.set_metadata(**self.args)
+        self._ann.__exit__(*exc)
         tr = self.tracer
         tr._depth -= 1
         tr.events.append((self.name, self.cat, self.t0, t1, self.tid,

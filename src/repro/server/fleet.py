@@ -29,7 +29,6 @@ from repro.core.knobs import Knobs
 from repro.core.query import Query, QueryResult, compile_query
 from repro.core.runtime import ClientSession, NetworkModel
 from repro.core.store import ObjectStore
-from repro.obs import metrics as obs_metrics
 from repro.obs.trace import span as obs_span
 from repro.server.session import FleetPacket, SessionManager
 from repro.server.zones import ZoneGrid, ZoneShardedStore
@@ -227,20 +226,10 @@ class FleetServer:
         zone session.  Acks from a superseded epoch are dropped — their seq
         numbering no longer matches the stream."""
         if epoch != int(self.epoch[c]):
-            reg = obs_metrics.get_registry()
-            if reg is not None:
-                reg.counter("fleet_stale_acks_total",
-                            "acks dropped for a superseded epoch").inc(
-                                client=int(c))
             return
         self.epoch_fresh[c] = False    # client adopted: later packets cont
         self.last_ack_tick[c] = tick
         self.sessions[zone].ack(c, seq)
-        reg = obs_metrics.get_registry()
-        if reg is not None:
-            reg.counter("fleet_acks_total",
-                        "cumulative acks applied").inc(client=int(c),
-                                                       zone=int(zone))
 
     def ack_tick(self, packets: list, *, tick: int) -> int:
         """Batched ack of one tick's own packets — the always-connected
@@ -260,10 +249,6 @@ class FleetServer:
         if acked.any():
             self.epoch_fresh[acked] = False
             self.last_ack_tick[acked] = tick
-        reg = obs_metrics.get_registry()
-        if reg is not None and n:
-            reg.counter("fleet_acks_total",
-                        "cumulative acks applied").inc(n, batched=1)
         return n
 
     def request_resync(self, c: int):
@@ -271,10 +256,6 @@ class FleetServer:
         state under a bumped epoch (its reorder buffers restart too)."""
         with obs_span("fleet.resync", cat="sync", client=int(c)):
             self._bump_epoch(c, fresh=False)
-        reg = obs_metrics.get_registry()
-        if reg is not None:
-            reg.counter("fleet_resyncs_total",
-                        "server-side resync rollbacks").inc(client=int(c))
 
     def maintain(self, *, tick: int, deliverable: np.ndarray,
                  retx_ticks: int):
@@ -352,7 +333,6 @@ class FleetServer:
                 epoch=self.epoch, fresh=self.epoch_fresh, now=tick))
                 for z in zs]
             sp.set(zones_collected=len(out))
-        self._tick_metrics(out)
         return out
 
     def _epoch_catchup(self, deliverable: np.ndarray,
@@ -392,17 +372,7 @@ class FleetServer:
         with obs_span("fleet.tick_finish", cat="sync"):
             out = [(z, self.sessions[z].collect_finish(p))
                    for z, p in started]
-        self._tick_metrics(out)
         return out
-
-    def _tick_metrics(self, out: list) -> None:
-        reg = obs_metrics.get_registry()
-        if reg is not None and out:
-            cnt = reg.counter("fleet_sent_bytes_total",
-                              "downstream wire bytes by client/zone")
-            for z, pkt in out:
-                for c in np.nonzero(pkt.nbytes)[0]:
-                    cnt.inc(int(pkt.nbytes[c]), client=int(c), zone=int(z))
 
     def per_client_nbytes(self, packets: list) -> np.ndarray:
         total = np.zeros((self.n_clients,), np.int64)

@@ -36,7 +36,7 @@ from repro.core import geometry as geo
 from repro.core.knobs import Knobs
 from repro.core.local_map import UpdateBatch, compute_priority
 from repro.core.store import ObjectStore, deleted_mask
-from repro.obs.trace import span as obs_span
+from repro.obs.trace import get_tracer, span as obs_span
 from repro.core.updates import (_HEADER_B, PROTO_HEADER_NBYTES,
                                 TOMBSTONE_NBYTES, UpdatePacket,
                                 class_budget_table)
@@ -84,23 +84,11 @@ def _downsample_gather(points: jax.Array, n_points: jax.Array,
     return jnp.where(valid[..., None], out, 0.0), n_out
 
 
-def _collect_fleet_impl(store: ObjectStore, synced: jax.Array,
-                        ever_sent: jax.Array, clear_mask: jax.Array,
-                        mask_c: jax.Array,
-                        min_obs: jax.Array, user_pos: jax.Array,
-                        interest_embeds, class_budgets: jax.Array, *,
-                        budget: int, points_budget: int, knobs: Knobs):
-    """One update tick for the whole fleet in a single dispatch.
-
-    ``class_budgets`` [256] is the per-class client point budget table
-    (updates.class_budget_table) — the fleet path honors
-    ``Knobs.class_point_overrides`` row-by-row exactly like the
-    single-client gather.
-
-    Returns (FleetBatch, new_synced [C, N], new_ever [C, N], nbytes [C],
-    counts [C], idx [C, U] — the store slots behind each packet row, for
-    the sender's in-flight/ack bookkeeping).
-    """
+def _changed(store: ObjectStore, synced: jax.Array, ever_sent: jax.Array,
+             clear_mask: jax.Array, mask_c: jax.Array, min_obs: jax.Array):
+    """The collect's eligibility predicate, shared by the collect and the
+    owed-row count: (synced, ever_sent) with the freed slots cleared, and
+    the [C, N] ``changed`` and ``tomb`` masks."""
     # slots freed since the last collect (reset_slots) clear INSIDE the
     # dispatch: the [N] mask rides in as 1 KB of host data instead of two
     # eager [C, N] where-ops materializing fresh sync arrays every free —
@@ -119,6 +107,39 @@ def _collect_fleet_impl(store: ObjectStore, synced: jax.Array,
     tomb = (dele[None] & ever_sent
             & (store.version[None] > synced))
     changed = (live | tomb) & mask_c[:, None]
+    return synced, ever_sent, changed, tomb
+
+
+@jax.jit
+def _changed_counts(store: ObjectStore, synced: jax.Array,
+                    ever_sent: jax.Array, clear_mask: jax.Array,
+                    mask_c: jax.Array, min_obs: jax.Array) -> jax.Array:
+    """[C] rows each client is owed before the collect's budget cut."""
+    _, _, changed, _ = _changed(store, synced, ever_sent, clear_mask,
+                                mask_c, min_obs)
+    return changed.sum(axis=1, dtype=jnp.int32)
+
+
+def _collect_fleet_impl(store: ObjectStore, synced: jax.Array,
+                        ever_sent: jax.Array, clear_mask: jax.Array,
+                        mask_c: jax.Array,
+                        min_obs: jax.Array, user_pos: jax.Array,
+                        interest_embeds, class_budgets: jax.Array, *,
+                        budget: int, points_budget: int, knobs: Knobs):
+    """One update tick for the whole fleet in a single dispatch.
+
+    ``class_budgets`` [256] is the per-class client point budget table
+    (updates.class_budget_table) — the fleet path honors
+    ``Knobs.class_point_overrides`` row-by-row exactly like the
+    single-client gather.
+
+    Returns (FleetBatch, new_synced [C, N], new_ever [C, N], nbytes [C],
+    counts [C], idx [C, U] — the store slots behind each packet row, for
+    the sender's in-flight/ack bookkeeping).
+    """
+    synced, ever_sent, changed, tomb = _changed(
+        store, synced, ever_sent, clear_mask, mask_c, min_obs)
+    dele = deleted_mask(store)
     pri = jax.vmap(lambda up: compute_priority(
         store.embed, store.label, store.centroid, user_pos=up, knobs=knobs,
         interest_embeds=interest_embeds))(user_pos)          # [C, N]
@@ -195,6 +216,8 @@ class _PendingCollect(NamedTuple):
     scrub: np.ndarray = None   # [N] bool — slots freed AFTER issue; their
     #                            rows must not enter in-flight/ever_sent
     #                            bookkeeping at finish (deferred pipeline)
+    changed: jax.Array = None  # [C] device — rows owed before the budget
+    #                            cut; counted only while a tracer is on
 
 
 @dataclass
@@ -478,11 +501,16 @@ class SessionManager:
         fn = _collect_fleet_donated if self.donate else _collect_fleet
         clear = jnp.asarray(self._pending_clear)
         self._pending_clear = np.zeros((self.capacity,), bool)
+        mask_d, min_obs = jnp.asarray(mask), jnp.asarray(self.min_obs)
+        # the owed-row count reads the sync state the collect donates, so
+        # it is issued first
+        changed = None if get_tracer() is None else _changed_counts(
+            store, self.sync.synced_version, self.sync.ever_sent, clear,
+            mask_d, min_obs)
         with obs_span("session.collect_fleet", cat="sync", zone=zone) as sp:
             batch, new_synced, new_ever, nbytes, counts, idx = fn(
                 store, self.sync.synced_version, self.sync.ever_sent,
-                clear, jnp.asarray(mask),
-                jnp.asarray(self.min_obs), jnp.asarray(self.user_pos),
+                clear, mask_d, min_obs, jnp.asarray(self.user_pos),
                 self.interest_embeds, self._class_budgets, budget=self.budget,
                 points_budget=self.knobs.max_object_points_client,
                 knobs=self.knobs)
@@ -495,20 +523,37 @@ class SessionManager:
         self._open_scrubs.append(scrub)
         return _PendingCollect(batch=batch, nbytes=nbytes, counts=counts,
                                idx=idx, mask=mask, zone=zone, epoch=epoch,
-                               fresh=fresh, now=now, scrub=scrub)
+                               fresh=fresh, now=now, scrub=scrub,
+                               changed=changed)
 
     def collect_finish(self, p: _PendingCollect) -> FleetPacket:
         """Materialize an issued collect: host transfer + seq/in-flight
         bookkeeping.  Finishing in issue order keeps the packets
         byte-identical to the sequential ``collect`` path."""
+        with obs_span("session.collect_finish", cat="sync") as sp:
+            pkt = self._finish(p)
+            if sp.on:
+                sp.set(zone=p.zone, issue_tick=p.now,
+                       clients=int(p.mask.sum()),
+                       rows_shipped=int(pkt.counts.sum()),
+                       bytes=int(pkt.nbytes.sum()))
+                if p.changed is not None:
+                    with obs_span("host.fetch", cat="sync", what="owed"):
+                        changed = np.asarray(p.changed)
+                    sp.set(rows_owed=int((changed - pkt.counts).sum()))
+        return pkt
+
+    def _finish(self, p: _PendingCollect) -> FleetPacket:
         batch = p.batch
-        counts = np.asarray(p.counts)
-        nbytes = np.asarray(p.nbytes).astype(np.int64)
+        with obs_span("host.fetch", cat="sync", what="counts"):
+            counts = np.asarray(p.counts)
+            nbytes = np.asarray(p.nbytes).astype(np.int64)
         seqs = np.full((self.n_clients,), -1, np.int64)
         if counts.any():
-            idx_h = np.asarray(p.idx)
-            valid_h = np.asarray(batch.valid)
-            vers_h = np.asarray(batch.version)
+            with obs_span("host.fetch", cat="sync", what="rows"):
+                idx_h = np.asarray(p.idx)
+                valid_h = np.asarray(batch.valid)
+                vers_h = np.asarray(batch.version)
             stamp = self.tick if p.now is None else p.now
             scrubbed = p.scrub is not None and p.scrub.any()
             for c in np.nonzero(counts)[0]:

@@ -30,6 +30,7 @@ import jax.numpy as jnp
 from repro.core.knobs import Knobs
 from repro.core.store import ObjectStore, deleted_mask, init_store
 from repro.core.updates import _bucket
+from repro.obs.trace import span as obs_span
 
 
 @dataclass(frozen=True)
@@ -225,60 +226,67 @@ class ZoneShardedStore:
         SessionManager.reset_slots before the slot is reused — and per-zone
         dirtiness flags so clean zones can skip their next collect.
         """
-        active = np.asarray(store.active)
-        version = np.asarray(store.version)
-        ids = np.asarray(store.ids)
-        cent = np.asarray(store.centroid)
-        # tombstones mirror like live rows (routed by their retained
-        # centroid): the shard must hold the version-bumped deletion until
-        # every subscriber has shipped it; once the global store retires
-        # the slot the row vanishes from `now` and the shard slot is freed
-        gidx = np.nonzero(active | np.asarray(deleted_mask(store)))[0]
-        Z = self.grid.n_zones
-        now = [dict() for _ in range(Z)]
-        if len(gidx):
-            zids = self.grid.zone_of(cent[gidx])
-            for g, z in zip(gidx, zids):
-                now[int(z)][int(ids[g])] = int(g)
+        with obs_span("zones.refresh", cat="sync") as sp:
+            with obs_span("host.fetch", cat="sync", what="store"):
+                active = np.asarray(store.active)
+                version = np.asarray(store.version)
+                ids = np.asarray(store.ids)
+                cent = np.asarray(store.centroid)
+                dele = np.asarray(deleted_mask(store))
+            # tombstones mirror like live rows (routed by their retained
+            # centroid): the shard must hold the version-bumped deletion until
+            # every subscriber has shipped it; once the global store retires
+            # the slot the row vanishes from `now` and the shard slot is freed
+            gidx = np.nonzero(active | dele)[0]
+            Z = self.grid.n_zones
+            now = [dict() for _ in range(Z)]
+            if len(gidx):
+                zids = self.grid.zone_of(cent[gidx])
+                for g, z in zip(gidx, zids):
+                    now[int(z)][int(ids[g])] = int(g)
 
-        freed_per_zone, changed_per_zone = [], []
-        for z in range(Z):
-            slot = self._slot[z]
-            freed, g_list, s_list = [], [], []
-            for oid in [o for o in slot if o not in now[z]]:
-                s = slot.pop(oid)
-                self._ver[z][s] = -1
-                self._free[z].append(s)
-                freed.append(s)
-            for oid, g in now[z].items():
-                s = slot.get(oid)
-                if s is None:
-                    if not self._free[z]:
-                        self._dropped_oids.add(oid)
-                        continue
-                    s = self._free[z].pop()
-                    slot[oid] = s
-                if self._ver[z][s] != version[g]:
-                    self._ver[z][s] = version[g]
-                    g_list.append(g)
-                    s_list.append(s)
-            freed_per_zone.append(freed)
-            changed_per_zone.append(bool(freed or g_list))
-            if freed or g_list:
-                B = _bucket(max(len(g_list), 1))
-                gb, gv = _pad_idx(g_list, B)
-                sb, _ = _pad_idx(s_list, B)
-                db, dv = _pad_idx(freed, _bucket(max(len(freed), 1)))
-                rows = _gather_rows(store, gb)
-                if self.devices is not None:
-                    rows = jax.device_put(rows, self.devices[z])
-                self.zones[z] = _zone_scatter(self.zones[z], rows, sb, gv,
-                                              db, dv)
-                # cluster-index maintenance rides the same delta: exactly
-                # the scattered + freed shard slots are re-indexed
-                zidx = self.indexes.get(z)
-                if zidx is not None:
-                    zidx.update_slots(self.zones[z], s_list + freed)
+            freed_per_zone, changed_per_zone, n_copied = [], [], 0
+            for z in range(Z):
+                slot = self._slot[z]
+                freed, g_list, s_list = [], [], []
+                for oid in [o for o in slot if o not in now[z]]:
+                    s = slot.pop(oid)
+                    self._ver[z][s] = -1
+                    self._free[z].append(s)
+                    freed.append(s)
+                for oid, g in now[z].items():
+                    s = slot.get(oid)
+                    if s is None:
+                        if not self._free[z]:
+                            self._dropped_oids.add(oid)
+                            continue
+                        s = self._free[z].pop()
+                        slot[oid] = s
+                    if self._ver[z][s] != version[g]:
+                        self._ver[z][s] = version[g]
+                        g_list.append(g)
+                        s_list.append(s)
+                freed_per_zone.append(freed)
+                changed_per_zone.append(bool(freed or g_list))
+                n_copied += len(g_list)
+                if freed or g_list:
+                    B = _bucket(max(len(g_list), 1))
+                    gb, gv = _pad_idx(g_list, B)
+                    sb, _ = _pad_idx(s_list, B)
+                    db, dv = _pad_idx(freed, _bucket(max(len(freed), 1)))
+                    rows = _gather_rows(store, gb)
+                    if self.devices is not None:
+                        rows = jax.device_put(rows, self.devices[z])
+                    self.zones[z] = _zone_scatter(self.zones[z], rows, sb, gv,
+                                                  db, dv)
+                    # cluster-index maintenance rides the same delta: exactly
+                    # the scattered + freed shard slots are re-indexed
+                    zidx = self.indexes.get(z)
+                    if zidx is not None:
+                        zidx.update_slots(self.zones[z], s_list + freed)
+            if sp.on:
+                sp.set(rows_changed=n_copied,
+                       rows_freed=sum(len(f) for f in freed_per_zone))
         return freed_per_zone, changed_per_zone
 
     # ------------------------------------------------------------------

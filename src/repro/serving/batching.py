@@ -18,6 +18,8 @@ from typing import Any, Callable
 
 import numpy as np
 
+from repro.obs.trace import span as obs_span
+
 
 @dataclass(order=True)
 class Request:
@@ -64,17 +66,21 @@ class BatchScheduler:
         """One engine iteration: fill the batch, run, retire completions."""
         now = time.perf_counter()
         self._hedge_stragglers(now)
-        batch = []
-        while self.waiting and len(batch) < self.batch_size:
-            req = heapq.heappop(self.waiting)
-            if req.rid in self.done:      # hedged duplicate already served
-                continue
-            req.started_at = now
-            self.running[req.rid] = req
-            batch.append(req)
-        if not batch:
-            return {}
-        results = self.step_fn([r.payload for r in batch])
+        with obs_span("query.batch", cat="query") as sp:
+            batch = []
+            while self.waiting and len(batch) < self.batch_size:
+                req = heapq.heappop(self.waiting)
+                if req.rid in self.done:  # hedged duplicate already served
+                    continue
+                req.started_at = now
+                self.running[req.rid] = req
+                batch.append(req)
+            if not batch:
+                return {}
+            if sp.on:
+                sp.set(rids=[r.rid for r in batch],
+                       wait_ms=[(now - r.enqueued_at) * 1e3 for r in batch])
+            results = self.step_fn([r.payload for r in batch])
         out = {}
         for req, res in zip(batch, results):
             if req.rid not in self.done:  # first completion wins
@@ -111,13 +117,16 @@ class PendingResult:
         if self._out is None:
             from repro.core.query import QueryResult
             i = self._i
-            oids = np.asarray(self._res.oids[i])
-            scores = np.asarray(self._res.scores[i])
+            with obs_span("host.fetch", cat="query", what="result"):
+                oids = np.asarray(self._res.oids[i])
+                scores = np.asarray(self._res.scores[i])
+                slots = None if self._legacy \
+                    else np.asarray(self._res.slots[i])
             if self._legacy:
                 self._out = (int(oids[0]), float(scores[0]))
             else:
                 self._out = QueryResult(oids=oids, scores=scores,
-                                        slots=np.asarray(self._res.slots[i]))
+                                        slots=slots)
             self._res = None           # release the batched device arrays
         return self._out
 

@@ -278,7 +278,7 @@ class ServingLoop:
 
     def _query_tick(self, t: int) -> dict:
         out = {}
-        with obs_span("serving.query", cat="query", mode=self._mode):
+        with obs_span("serving.query", cat="query", mode=self._mode) as sp:
             now = time.perf_counter()
             if self.loadgen is not None:
                 for cid, spec in self.loadgen.arrivals[t]:
@@ -293,22 +293,33 @@ class ServingLoop:
                     for rid in served:
                         self.loadgen.note_served(rid, claim)
                 out.update(served)
+            if sp.on:
+                # the queued requests' ages so far: a request the scheduler
+                # never picks shows here, not in any ``query.batch``
+                end = time.perf_counter()
+                queued = self.scheduler.waiting
+                sp.set(waiting=len(queued),
+                       waiting_ms=[round((end - r.enqueued_at) * 1e3, 1)
+                                   for r in queued])
         return out
 
     def _resolve(self, out: dict) -> None:
         """Materialize this tick's query results — the ONE per-tick fence
         in overlapped mode (waits only on the query dispatches: they read
         the published front, never the in-flight ingest)."""
-        for rid, res in out.items():
-            if isinstance(res, PendingResult):
-                res = res.resolve()
-                self.scheduler.done[rid] = res
-            self.results[rid] = res
-            self.n_served += 1
-        if self.loadgen is not None and out:
-            done = time.perf_counter()
-            for rid in out:
-                self.loadgen.note_resolved(rid, done)
+        with obs_span("serving.resolve", cat="query") as sp:
+            if sp.on:
+                sp.set(rids=list(out))
+            for rid, res in out.items():
+                if isinstance(res, PendingResult):
+                    res = res.resolve()
+                    self.scheduler.done[rid] = res
+                self.results[rid] = res
+                self.n_served += 1
+            if self.loadgen is not None and out:
+                done = time.perf_counter()
+                for rid in out:
+                    self.loadgen.note_resolved(rid, done)
 
     # ------------------------------------------------------------------
     def tick(self) -> None:
@@ -318,19 +329,20 @@ class ServingLoop:
         new = self._issue_ingest(d)
         self._sync_tick(t)
         out = self._query_tick(t)
-        if self.overlap:
-            self.store.publish(new, pending=d)
-        else:
-            # synchronous mode never touched the back buffer: swap the
-            # front pointer only (the stale clone is never donated)
-            self.store.front = new
-            self.store.pending = None
-            self.store.version += 1
-        if self.index is not None:
-            # index maintenance rides the publish: update from the delta's
-            # touched slots against the NEW publish buffer
-            self.index.update_slots(self.store.front,
-                                    np.asarray(d.slots))
+        with obs_span("serving.publish", cat="ingest"):
+            if self.overlap:
+                self.store.publish(new, pending=d)
+            else:
+                # synchronous mode never touched the back buffer: swap the
+                # front pointer only (the stale clone is never donated)
+                self.store.front = new
+                self.store.pending = None
+                self.store.version += 1
+            if self.index is not None:
+                # index maintenance rides the publish: update from the
+                # delta's touched slots against the NEW publish buffer
+                self.index.update_slots(self.store.front,
+                                        np.asarray(d.slots))
         if self.overlap:
             # software pipelining: frame LAST tick's packets and resolve
             # LAST tick's queries now, carry this tick's — their device
@@ -347,13 +359,7 @@ class ServingLoop:
         else:
             self._resolve(out)
         self.tick_idx += 1
-        ms = (time.perf_counter() - wall0) * 1e3
-        self.tick_ms.append(ms)
-        reg = obs_metrics.get_registry()
-        if reg is not None:
-            reg.histogram("serving_tick_ms",
-                          "serving loop tick wall time").observe(
-                              ms, mode=self._mode)
+        self.tick_ms.append((time.perf_counter() - wall0) * 1e3)
 
     def run(self, n_ticks: int) -> dict:
         for _ in range(n_ticks):

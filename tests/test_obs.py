@@ -316,3 +316,96 @@ def test_lq_curve_missing_file(tmp_path):
     bad = tmp_path / "bad.json"
     bad.write_text("{not json")
     assert load_lq_curve(bad) is None
+
+
+# ------------------------------------------------ spans on the profiler
+def _clock_marks(n: int = 5) -> list:
+    """perf_counter readings inside ``bench.clock`` annotations, as the
+    benchmark harness takes them around a profiled window."""
+    import time
+
+    import jax
+    marks = []
+    for _ in range(n):
+        with jax.profiler.TraceAnnotation("bench.clock"):
+            marks.append(time.perf_counter())
+    return marks
+
+
+@pytest.fixture(scope="module")
+def profiled_spans(tmp_path_factory):
+    """A CPU profiler trace around nested spans of an installed tracer:
+    (tracer events, planes loaded by ``bench.xplane.load``, clock marks)."""
+    import time
+
+    import jax
+    from bench import xplane
+    out = tmp_path_factory.mktemp("prof")
+    tr = Tracer()
+    prev = set_tracer(tr)
+    jax.profiler.start_trace(str(out))
+    try:
+        marks = _clock_marks()
+        from repro.obs import span
+        with span("outer", cat="test", tick=1):
+            with span("inner.a", cat="test") as sp:
+                time.sleep(0.002)
+                sp.set(rids=[3, 4], waiting=2)
+            for _ in range(3):
+                with span("inner.b", cat="test"):
+                    time.sleep(0.001)
+        marks += _clock_marks()
+    finally:
+        jax.profiler.stop_trace()
+        set_tracer(prev)
+    return list(tr.events), xplane.load(str(out)), marks
+
+
+def test_spans_land_on_the_profiler_host_plane(profiled_spans):
+    from bench import xplane
+    events, planes, _ = profiled_spans
+    assert {e[0] for e in events} == {"outer", "inner.a", "inner.b"}
+    for name in {e[0] for e in events}:
+        n = sum(1 for e in events if e[0] == name)
+        assert len(xplane.host_events(planes, name)) == n, name
+
+
+def test_clock_marks_map_spans_onto_their_annotations(profiled_spans):
+    """The harness's offset (median over its ``bench.clock`` marks of
+    trace start minus perf_counter) puts each span within 0.5 ms of its
+    annotation."""
+    from bench import xplane
+    events, planes, marks = profiled_spans
+    found = xplane.host_events(planes, "bench.clock")
+    assert len(found) == len(marks)
+    offs = sorted(s - p * 1e9 for (s, _), p in zip(found, marks))
+    off = offs[len(offs) // 2]
+    for name in {e[0] for e in events}:
+        ours = sorted((t0, t1) for n, _, t0, t1, _, _ in events
+                      if n == name)
+        for (t0, t1), (s, d) in zip(ours,
+                                    xplane.host_events(planes, name)):
+            assert abs(t0 * 1e9 + off - s) < 0.5e6, name
+            assert abs(t1 * 1e9 + off - (s + d)) < 0.5e6, name
+
+
+def test_span_off_creates_no_annotation(tmp_path):
+    import jax
+    from bench import xplane
+    from repro.obs import span
+    prev = set_tracer(None)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        sp = span("off.span", cat="test", zone=1)
+        with sp as s:
+            assert s.on is False
+            s.set(rows=[1, 2])
+        assert span("off.other") is sp        # the shared no-op
+        _clock_marks(1)
+    finally:
+        jax.profiler.stop_trace()
+        set_tracer(prev)
+    planes = xplane.load(str(tmp_path))
+    assert xplane.host_events(planes, "bench.clock")
+    assert not xplane.host_events(planes, "off.span")
+    assert not xplane.host_events(planes, "off.other")

@@ -8,6 +8,8 @@ must produce byte-identical outputs to their copying twins, and the whole
 loop (and the scenario engine under ``async_loop=True``) must replay
 bit-identically against the synchronous schedule.
 """
+import time
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -436,3 +438,179 @@ def test_serving_loop_sharded_session_tier_byte_identity():
                     vb[b.roster.members[s]] = np.asarray(
                         part.sync.synced_version)
             np.testing.assert_array_equal(va, vb, err_msg=f"zone {z}")
+
+
+# ---------------------------------------------------------------------------
+# tracing the served tick: request ids, queue waits, backlog, owed rows
+# ---------------------------------------------------------------------------
+def _traced_loop(tracer, n_ticks=8, C=6):
+    """The overlapped tiny loop with ``tracer`` installed (None: off);
+    returns (loop, Request objects by rid, per-tick backlog, packets,
+    per-tick (clock before, clock after, queued ``enqueued_at`` in heap
+    order))."""
+    from repro.obs.trace import set_tracer
+    store = _store()
+    srv = FleetServer(knobs=KN, embed_dim=E, n_clients=C,
+                      grid=ZoneGrid.for_room(16.0, 2, 2), budget=4,
+                      donate=True)
+    lg = LoadGenerator(LoadSpec(n_clients=C, n_ticks=n_ticks, base_hz=6.0,
+                                burst_hz=60.0, burst_prob=0.2, seed=2),
+                       embed_dim=E)
+    for c in range(C):
+        srv.join(c, lg.pose_at(c, 0), 6.0)
+    loop = ServingLoop(server=srv, store=SnapshotStore.of(store),
+                       ingest=_stream(n_ticks=n_ticks), loadgen=lg,
+                       overlap=True, batch_size=4, max_batches_per_tick=1)
+    sched = loop.scheduler
+    reqs, backlog, packets, queued = {}, [], [], []
+    submit, query_tick = sched.submit, loop._query_tick
+    finish = srv.tick_finish
+
+    def submit_rec(payload, **kw):
+        rid = submit(payload, **kw)
+        reqs[rid] = next(r for r in sched.waiting if r.rid == rid)
+        return rid
+
+    def query_tick_rec(t):
+        before = time.perf_counter()
+        out = query_tick(t)
+        queued.append((before, time.perf_counter(),
+                       [r.enqueued_at for r in sched.waiting]))
+        backlog.append(len(sched.waiting))
+        return out
+
+    def finish_rec(started):
+        out = finish(started)
+        packets.extend((z, np.asarray(p.nbytes).copy(),
+                        np.asarray(p.batch.oid).copy(),
+                        np.asarray(p.batch.valid).copy()) for z, p in out)
+        return out
+
+    sched.submit, loop._query_tick = submit_rec, query_tick_rec
+    srv.tick_finish = finish_rec
+    prev = set_tracer(tracer)
+    try:
+        loop.run(n_ticks)
+    finally:
+        set_tracer(prev)
+    return loop, reqs, backlog, packets, queued
+
+
+def _args(tracer, name):
+    return [e[5] or {} for e in tracer.events if e[0] == name]
+
+
+@pytest.fixture(scope="module")
+def traced_run():
+    from repro.obs import Tracer
+    tr = Tracer()
+    return (tr,) + _traced_loop(tr)
+
+
+def test_each_query_in_one_batch_and_one_resolve(traced_run):
+    tr, loop, reqs, _, _, _ = traced_run
+    assert len(reqs) > 0 and set(loop.results) == set(reqs)
+    for name in ("query.batch", "serving.resolve"):
+        rids = [r for a in _args(tr, name) for r in a.get("rids", ())]
+        assert sorted(rids) == sorted(reqs), name
+
+
+def test_query_wait_is_step_start_minus_enqueue(traced_run):
+    tr, _, reqs, _, _, _ = traced_run
+    batches = [a for a in _args(tr, "query.batch") if "rids" in a]
+    assert batches
+    for a in batches:
+        assert len(a["wait_ms"]) == len(a["rids"])
+        for rid, w in zip(a["rids"], a["wait_ms"]):
+            r = reqs[rid]
+            assert w == (r.started_at - r.enqueued_at) * 1e3
+    # one request per batch slot and one batch per tick: a queue forms
+    assert max(w for a in batches for w in a["wait_ms"]) > 0
+
+
+def test_query_backlog_matches_the_scheduler(traced_run):
+    tr, _, _, backlog, _, _ = traced_run
+    waiting = [a["waiting"] for a in _args(tr, "serving.query")]
+    # the drain after the last tick steps the scheduler outside any tick
+    assert waiting == backlog and max(waiting) > 0
+
+
+def test_queued_ages_are_the_schedulers_requests(traced_run):
+    """``waiting_ms`` of ``serving.query`` holds, in heap order, the age of
+    every request still queued when the span closes (0.1 ms rounding)."""
+    tr, _, _, _, _, queued = traced_run
+    spans = _args(tr, "serving.query")
+    assert len(spans) == len(queued)
+    for a, (before, after, enq) in zip(spans, queued):
+        assert len(a["waiting_ms"]) == a["waiting"] == len(enq)
+        for age, t in zip(a["waiting_ms"], enq):
+            assert (before - t) * 1e3 - 0.05 <= age <= (after - t) * 1e3 + 0.05
+    assert max(max(a["waiting_ms"], default=0) for a in spans) > 0
+
+
+def test_tracing_leaves_results_and_packets_unchanged(traced_run):
+    _, on, _, _, pk_on, _ = traced_run
+    off, _, _, pk_off, _ = _traced_loop(None)
+    assert set(on.results) == set(off.results)
+    for rid in on.results:
+        assert np.array_equal(on.results[rid].oids, off.results[rid].oids)
+        assert np.array_equal(on.results[rid].scores,
+                              off.results[rid].scores)
+    assert on.sent_bytes == off.sent_bytes > 0
+    assert len(pk_on) == len(pk_off) > 0
+    for a, b in zip(pk_on, pk_off):
+        assert a[0] == b[0]
+        for x, y in zip(a[1:], b[1:]):
+            assert np.array_equal(x, y)
+
+
+def _owed_oracle(store, synced, ever_sent, mask, min_obs):
+    """Numpy count of the rows a collect must consider: live rows past the
+    client's synced version and tombstones of rows it was ever sent."""
+    act = np.asarray(store.active)
+    ver = np.asarray(store.version)
+    obs = np.asarray(store.obs_count)
+    dele = np.asarray(store.deleted)
+    newer = ver[None] > synced
+    live = act[None] & (obs[None] >= min_obs[:, None]) & newer
+    tomb = dele[None] & ever_sent & newer
+    return ((live | tomb) & mask[:, None]).sum(axis=1)
+
+
+@pytest.mark.parametrize("budget", [4, 128])
+def test_rows_owed_counts_changed_rows_left_unshipped(budget):
+    """``rows_owed`` of ``session.collect_finish`` is the numpy count of
+    changed rows minus the rows shipped: positive while the budget binds,
+    zero once it does not."""
+    from repro.obs import Tracer
+    from repro.obs.trace import set_tracer
+    from repro.server.session import SessionManager
+    store = _store()
+    C = 5
+    sub = np.array([True, True, False, True, True])
+    sm = SessionManager(knobs=KN, n_clients=C, capacity=CAP, budget=budget,
+                        subscribed=sub.copy())
+    stream = _stream(n_ticks=4, seed=7)
+    tr = Tracer()
+    prev = set_tracer(tr)
+    want = []
+    try:
+        for t in range(4):
+            if t:
+                store = apply_delta(store, stream.delta_at(t))
+            synced = np.asarray(sm.sync.synced_version)
+            ever = np.asarray(sm.sync.ever_sent)
+            changed = _owed_oracle(store, synced, ever, sub, sm.min_obs)
+            pkt = sm.collect_finish(sm.collect_start(store, now=t))
+            shipped = np.asarray(pkt.counts)
+            assert np.array_equal(shipped, np.minimum(changed, sm.budget))
+            want.append(dict(rows_owed=int((changed - shipped).sum()),
+                             rows_shipped=int(shipped.sum()),
+                             clients=int(sub.sum()), issue_tick=t,
+                             bytes=int(pkt.nbytes.sum()), zone=0))
+    finally:
+        set_tracer(prev)
+    got = _args(tr, "session.collect_finish")
+    assert got == want
+    owed = [w["rows_owed"] for w in want]
+    assert (owed[0] > 0) == (budget < NLIVE)
