@@ -16,7 +16,7 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Callable
 
-import numpy as np
+import jax
 
 from repro.obs.trace import span as obs_span
 
@@ -97,37 +97,67 @@ class BatchScheduler:
         return self.done
 
 
+def _result_row(oids, scores, slots, i: int, legacy: bool):
+    """Row ``i`` of a fetched [Q, k] result as the step fn returns it: the
+    top hit ``(oid, score)`` for a legacy embedding payload, else the
+    request's ``QueryResult`` of numpy rows."""
+    if legacy:
+        return (int(oids[i, 0]), float(scores[i, 0]))
+    from repro.core.query import QueryResult
+    return QueryResult(oids=oids[i], scores=scores[i], slots=slots[i])
+
+
+class _BatchFetch:
+    """One dispatched group's [Q, k] ``QueryResult``, shared by its rows.
+
+    The host copy of ``oids``, ``scores`` and ``slots`` starts when the
+    group is dispatched, so it lands while the rest of the tick runs;
+    ``host`` waits for it once, on the first row resolved, and drops the
+    device arrays."""
+
+    __slots__ = ("_dev", "_host", "_rows")
+
+    def __init__(self, res, rows: int):
+        self._dev = (res.oids, res.scores, res.slots)
+        for x in self._dev:
+            x.copy_to_host_async()
+        self._host = None
+        self._rows = rows              # real rows served (no padding)
+
+    def host(self) -> tuple:
+        if self._host is None:
+            with obs_span("host.fetch", cat="query", what="result") as sp:
+                if sp.on:
+                    sp.set(rows=self._rows,
+                           ready=all(x.is_ready() for x in self._dev))
+                self._host = jax.device_get(self._dev)
+            self._dev = None
+        return self._host
+
+
 class PendingResult:
     """A query result whose dispatch has been issued but not materialized.
 
     ``make_query_step_fn(block=False)`` stores one of these per request in
-    ``BatchScheduler.done``: the whole group's batched QueryResult stays a
-    device array, and the serving loop resolves rows after its per-tick
-    fence instead of forcing a host sync inside the scheduler step (which
-    would serialize query dispatch with ingest/sync compute).  ``resolve``
-    is idempotent and returns exactly what the blocking path would have."""
+    ``BatchScheduler.done``.  The rows of one dispatched group share one
+    host copy of the group's [Q, k] result, started at dispatch; the
+    serving loop resolves rows after its tick instead of forcing a host
+    sync inside the scheduler step (which would serialize query dispatch
+    with ingest/sync compute).  The first row resolved waits for that copy
+    once, every other row slices it on the host.  ``resolve`` is
+    idempotent and returns exactly what the blocking path would have."""
 
-    __slots__ = ("_res", "_i", "_legacy", "_out")
+    __slots__ = ("_batch", "_i", "_legacy", "_out")
 
-    def __init__(self, res, i: int, legacy: bool):
-        self._res, self._i, self._legacy = res, i, legacy
+    def __init__(self, batch: _BatchFetch, i: int, legacy: bool):
+        self._batch, self._i, self._legacy = batch, i, legacy
         self._out = None
 
     def resolve(self):
         if self._out is None:
-            from repro.core.query import QueryResult
-            i = self._i
-            with obs_span("host.fetch", cat="query", what="result"):
-                oids = np.asarray(self._res.oids[i])
-                scores = np.asarray(self._res.scores[i])
-                slots = None if self._legacy \
-                    else np.asarray(self._res.slots[i])
-            if self._legacy:
-                self._out = (int(oids[0]), float(scores[0]))
-            else:
-                self._out = QueryResult(oids=oids, scores=scores,
-                                        slots=slots)
-            self._res = None           # release the batched device arrays
+            self._out = _result_row(*self._batch.host(), self._i,
+                                    self._legacy)
+            self._batch = None
         return self._out
 
 
@@ -169,19 +199,18 @@ def make_query_step_fn(get_map, *, k: int = 5, use_pallas: bool = False,
     for Query payloads.
 
     ``block=False`` returns ``PendingResult`` handles instead: the fused
-    dispatch is issued but no host transfer happens inside the step — the
-    overlapped serving loop fences once per tick and ``resolve``s then.
+    dispatch is issued and each group's one host copy is started, but
+    nothing waits for it inside the step — the overlapped serving loop
+    ``resolve``s a tick later, with one blocking read per group.
 
     ``get_index`` (optional) returns the current cluster index over the
     map, re-read every step like ``get_map`` — the serving loop keeps its
     index maintained against the PUBLISH buffer, so a two-stage plan is
     exact against the same snapshot the flat sweep would scan.
     """
-    import jax
     import jax.numpy as jnp
 
-    from repro.core.query import Query, QueryResult, execute_query, \
-        stack_queries
+    from repro.core.query import Query, execute_query, stack_queries
 
     def step_fn(payloads: list) -> list:
         m = get_map()
@@ -203,18 +232,13 @@ def make_query_step_fn(get_map, *, k: int = 5, use_pallas: bool = False,
             res = execute_query(m, batched, use_pallas=use_pallas,
                                 index=index)
             if not block:
+                batch = _BatchFetch(res, q)
                 for i, pos in enumerate(positions):
-                    results[pos] = PendingResult(res, i, legacy[pos])
+                    results[pos] = PendingResult(batch, i, legacy[pos])
                 continue
-            oids = np.asarray(res.oids)
-            scores = np.asarray(res.scores)
-            slots = np.asarray(res.slots)
+            host = jax.device_get((res.oids, res.scores, res.slots))
             for i, pos in enumerate(positions):
-                if legacy[pos]:
-                    results[pos] = (int(oids[i, 0]), float(scores[i, 0]))
-                else:
-                    results[pos] = QueryResult(oids=oids[i], scores=scores[i],
-                                               slots=slots[i])
+                results[pos] = _result_row(*host, i, legacy[pos])
         return results
 
     return step_fn

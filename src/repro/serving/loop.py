@@ -18,8 +18,9 @@ lets JAX's async dispatch overlap them:
   before materializing any packet (``SessionManager.collect_start`` /
   ``collect_finish``), with the [C, N] sync state donated.
 - **Queries** drain from the ``BatchScheduler`` with a non-blocking step
-  fn (``PendingResult`` handles); the loop fences ONCE per tick when it
-  resolves results for latency accounting, instead of once per batch.
+  fn (``PendingResult`` handles): each batch's host copy starts at
+  dispatch, and the loop resolves the results a tick later with one
+  blocking read per batch, not one per result.
 - **Publish** swaps the double buffer; the loop's cluster index (when
   enabled) is maintained against the publish buffer from the delta's
   touched slots, so a two-stage plan stays exact against the snapshot.
@@ -304,9 +305,10 @@ class ServingLoop:
         return out
 
     def _resolve(self, out: dict) -> None:
-        """Materialize this tick's query results — the ONE per-tick fence
-        in overlapped mode (waits only on the query dispatches: they read
-        the published front, never the in-flight ingest)."""
+        """Materialize this tick's query results — in overlapped mode one
+        blocking read per batch, on a copy started at dispatch (it waits
+        only on the query dispatches: they read the published front, never
+        the in-flight ingest)."""
         with obs_span("serving.resolve", cat="query") as sp:
             if sp.on:
                 sp.set(rids=list(out))

@@ -515,6 +515,71 @@ def test_each_query_in_one_batch_and_one_resolve(traced_run):
         assert sorted(rids) == sorted(reqs), name
 
 
+def test_one_result_fetch_per_served_batch(traced_run):
+    """Each ``query.batch`` that served rows is resolved by one
+    ``host.fetch`` read of its whole result, inside ``serving.resolve``:
+    ``rows`` counts the batch's real rows, ``ready`` says whether the read
+    found the copy landed."""
+    tr, loop, _, _, _, _ = traced_run
+    batches = [a for a in _args(tr, "query.batch") if "rids" in a]
+    fetches = [e for e in tr.events
+               if e[0] == "host.fetch" and e[5].get("what") == "result"]
+    assert len(fetches) == len(batches) > 0
+    # batches resolve in dispatch order, a tick after it
+    assert [e[5]["rows"] for e in fetches] == \
+        [len(a["rids"]) for a in batches]
+    assert sum(e[5]["rows"] for e in fetches) == len(loop.results)
+    assert all(type(e[5]["ready"]) is bool for e in fetches)
+    resolves = [(e[2], e[3]) for e in tr.events if e[0] == "serving.resolve"]
+    for e in fetches:
+        assert any(t0 <= e[2] and e[3] <= t1 for t0, t1 in resolves)
+
+
+def _step_payloads(store, legacy: bool) -> list:
+    """Ten payloads in two plan groups (legacy: ten raw embeddings, one)."""
+    rng = np.random.default_rng(11)
+    emb = rng.normal(size=(10, E)).astype(np.float32)
+    emb /= np.linalg.norm(emb, axis=-1, keepdims=True)
+    if legacy:
+        return [jnp.asarray(e) for e in emb]
+    cent = np.asarray(store.centroid)
+    return [Query(embed=jnp.asarray(e), k=5) if i % 3 else
+            Query(embed=jnp.asarray(e),
+                  near=(jnp.asarray(cent[i]), jnp.asarray(4.0)), k=5)
+            for i, e in enumerate(emb)]
+
+
+@pytest.mark.parametrize("legacy", [False, True], ids=["query", "legacy"])
+def test_pending_rows_equal_blocking_rows(legacy):
+    """``PendingResult`` rows of one step, resolved in any order and twice
+    each, equal the blocking step's rows bit for bit: same values, dtypes,
+    shapes and Python types."""
+    from repro.serving.batching import (PendingResult, make_query_step_fn,
+                                        resolve_results)
+    store = _store()
+    payloads = _step_payloads(store, legacy)
+    want = make_query_step_fn(lambda: store, pad_to=8)(payloads)
+    pending = make_query_step_fn(lambda: store, pad_to=8,
+                                 block=False)(payloads)
+    assert all(isinstance(p, PendingResult) for p in pending)
+    order = np.random.default_rng(3).permutation(len(pending))
+    for i in list(order) + list(order[::-1]):
+        got, ref = pending[i].resolve(), want[i]
+        if legacy:
+            assert got == ref and type(got[0]) is int \
+                and type(got[1]) is float
+            continue
+        for x, y in zip(got, ref):
+            assert x.dtype == y.dtype and x.shape == y.shape == (5,)
+            assert x.tobytes() == y.tobytes()
+    assert pending[0].resolve() is pending[0].resolve()
+    done = resolve_results({i: p for i, p in enumerate(pending)})
+    for i, ref in enumerate(want):
+        got = done[i]
+        assert got == ref if legacy else all(
+            x.tobytes() == y.tobytes() for x, y in zip(got, ref))
+
+
 def test_query_wait_is_step_start_minus_enqueue(traced_run):
     tr, _, reqs, _, _, _ = traced_run
     batches = [a for a in _args(tr, "query.batch") if "rids" in a]
