@@ -55,17 +55,34 @@ def association_scores(store: ObjectStore, det: Detections, *,
     dist2 = jnp.sum(
         jnp.square(cent_d[:, None, :] - store.centroid[None, :, :]), axis=-1)
     spatial = jnp.exp(-dist2 / (2 * spatial_sigma ** 2))   # [D,cap]
-    semantic = det.embed @ store.embed.T                   # cosine, unit norm
+    # cosine of unit vectors at full f32: the TPU's default f32 matmul is
+    # one bf16 pass (~1e-3 off), which moves decisions near the threshold
+    semantic = jnp.matmul(det.embed, store.embed.T,
+                          precision=jax.lax.Precision.HIGHEST)
     score = 0.5 * spatial + 0.5 * semantic
     score = jnp.where(store.active[None, :], score, -jnp.inf)
     score = jnp.where(det.valid[:, None], score, -jnp.inf)
     return score, cent_d
 
 
-def associate(store: ObjectStore, det: Detections, *, frame: jax.Array,
-              match_threshold: float = 0.6, point_budget: int = 2000,
-              ema: float = 0.25, det_centroid=None) -> ObjectStore:
+class Resolution(NamedTuple):
+    """How each detection of a frame was resolved ([D] each)."""
+    slot: jax.Array       # int32 slot written; cap where nothing was
+    target: jax.Array     # int32 best existing slot (argmax score)
+    score: jax.Array      # f32 its score; -inf for an invalid detection
+    matched: jax.Array    # bool merged into ``target`` (else inserted)
+
+
+def associate(store: ObjectStore, det: Detections, **kw) -> ObjectStore:
+    """``associate_rows`` without the per-detection resolution."""
+    return associate_rows(store, det, **kw)[0]
+
+
+def associate_rows(store: ObjectStore, det: Detections, *, frame: jax.Array,
+                   match_threshold: float = 0.6, point_budget: int = 2000,
+                   ema: float = 0.25, det_centroid=None):
     """Associate one frame's detections into the store. jit-able.
+    Returns (store, ``Resolution``).
 
     Fully batched resolve — no per-detection scan:
 
@@ -130,6 +147,9 @@ def associate(store: ObjectStore, det: Detections, *, frame: jax.Array,
     new_ver = jnp.where(is_match, store.version[j_star] + 1, 1)
     new_ids = jnp.where(is_match, store.ids[j_star], store.next_id + rank)
     n_inserted = jnp.minimum(do_insert.sum(), n_free).astype(jnp.int32)
+    res = Resolution(slot=tgt.astype(jnp.int32),
+                     target=j_star.astype(jnp.int32), score=best,
+                     matched=is_match)
     return store._replace(
         ids=store.ids.at[tgt].set(new_ids),
         active=store.active.at[tgt].set(True),
@@ -145,7 +165,7 @@ def associate(store: ObjectStore, det: Detections, *, frame: jax.Array,
         version=store.version.at[tgt].set(new_ver),
         last_seen=store.last_seen.at[tgt].set(frame),
         next_id=store.next_id + n_inserted,
-    )
+    ), res
 
 
 def associate_reference(store: ObjectStore, det: Detections, *,

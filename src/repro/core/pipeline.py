@@ -30,6 +30,7 @@ from __future__ import annotations
 import time
 from dataclasses import dataclass
 from functools import partial
+from typing import NamedTuple
 
 import numpy as np
 import jax
@@ -47,6 +48,49 @@ from repro.obs.trace import span as obs_span
 from repro.perception.embedder import OracleEmbedder
 
 LIFT_BUFFER = 4096   # uncapped per-object buffer (baseline mode)
+
+
+class KeyframeInputs(NamedTuple):
+    """One keyframe's detections as the fused ingest takes them (host
+    arrays, padded to ``max_detections_per_frame``)."""
+    depth: np.ndarray       # [H/r, W/r] f32 downsampled depth
+    masks: np.ndarray       # [D, H/r, W/r] bool instance masks
+    intrinsics: np.ndarray  # [4] f32, full resolution
+    pose: np.ndarray        # [4, 4] f32 cam -> world
+    cids: np.ndarray        # [D] int32 class ids
+    valid: np.ndarray       # [D] bool
+
+
+class KeyframeRecord(NamedTuple):
+    """What one keyframe's ingest wrote, from the same dispatch.  Per
+    detection [D]: the slot written (cap where none was), that row's id,
+    version, observation count, label, point count, centroid and
+    embedding after the write; the detection's best existing slot and its
+    score, and whether it merged there.  ``n_pruned``: slots the transient
+    prune turned off."""
+    slot: jax.Array
+    oid: jax.Array
+    version: jax.Array
+    obs: jax.Array
+    label: jax.Array
+    n_points: jax.Array
+    centroid: jax.Array     # [D, 3]
+    embed: jax.Array        # [D, E]
+    target: jax.Array
+    score: jax.Array
+    matched: jax.Array
+    n_pruned: jax.Array     # []
+
+
+def keyframe_record(st: ObjectStore, res: assoc.Resolution,
+                    n_pruned) -> KeyframeRecord:
+    row = jnp.minimum(res.slot, st.ids.shape[0] - 1)
+    return KeyframeRecord(
+        slot=res.slot, oid=st.ids[row], version=st.version[row],
+        obs=st.obs_count[row], label=st.label[row],
+        n_points=st.n_points[row], centroid=st.centroid[row],
+        embed=st.embed[row], target=res.target, score=res.score,
+        matched=res.matched, n_pruned=n_pruned.astype(jnp.int32))
 
 
 @dataclass
@@ -82,15 +126,16 @@ class MappingServer:
     embedder: OracleEmbedder
     mode: str = "semanticxr"        # "baseline" | "parallel" | "semanticxr"
     instrument: bool = False        # semanticxr: staged timings vs one dispatch
-    donate: bool = False            # donate the store to the fused ingest
+    donate: bool | None = None      # donate the store to the fused ingest
     #                                 dispatch: the pre-frame store is dead
     #                                 once process_frame rebinds self.store,
     #                                 so XLA updates the [cap, ...] arrays in
     #                                 place instead of copying them per
-    #                                 keyframe.  Opt-in: callers that hold a
-    #                                 pre-frame store reference (snapshot
-    #                                 readers, ablation oracles) must stay
-    #                                 on the copying path.
+    #                                 keyframe.  None = by backend
+    #                                 (ops.donate_default: on for a TPU).
+    #                                 Callers that hold a pre-frame store
+    #                                 reference (snapshot readers, ablation
+    #                                 oracles) pass False.
     store: ObjectStore = None
     frame_count: int = 0
     deferred: int = 0
@@ -98,6 +143,8 @@ class MappingServer:
 
     def __post_init__(self):
         kn = self.knobs
+        if self.donate is None:
+            self.donate = ops.donate_default()
         if self.store is None:
             self.store = store_from_knobs(kn, self.embedder.embed_dim)
         r = kn.depth_downsampling_ratio
@@ -127,7 +174,8 @@ class MappingServer:
         self._prune = jax.jit(lambda st, fr: assoc.prune_transients(
             st, frame=fr, min_obs=kn.min_obs_before_sync))
 
-        # the production path: ONE jitted dispatch per keyframe
+        # the production path: ONE jitted dispatch per keyframe, returning
+        # the pruned store and the record of what it wrote
         def ingest_frame(st, depth_lo, masks, intr, pose, cids, valid, key,
                          frame):
             embs = self.embedder.embed_observation(cids, key)
@@ -136,22 +184,27 @@ class MappingServer:
                 lift_cap=LIFT_BUFFER)
             det = assoc.Detections(embed=embs, label=cids, points=pts,
                                    n_points=ns, valid=valid)
-            st = assoc.associate(st, det, frame=frame, point_budget=budget,
-                                 det_centroid=cent)
-            return assoc.prune_transients(st, frame=frame,
-                                          min_obs=kn.min_obs_before_sync)
+            st, res = assoc.associate_rows(st, det, frame=frame,
+                                           point_budget=budget,
+                                           det_centroid=cent)
+            out = assoc.prune_transients(st, frame=frame,
+                                         min_obs=kn.min_obs_before_sync)
+            n_pruned = (st.active & ~out.active).sum()
+            return out, keyframe_record(out, res, n_pruned)
 
-        self._ingest = jax.jit(ingest_frame, donate_argnums=(0,)) \
-            if self.donate else jax.jit(ingest_frame)
+        self._ingest = jax.jit(ingest_frame,
+                               donate_argnums=(0,) if self.donate else ())
 
     # ------------------------------------------------------------------
     def _detect(self, frame: Frame, classes: dict):
         """Detector stand-in: GT instance masks + mapping-policy filters.
 
-        One vectorized bbox/area pass over the instance map — no per-object
-        ``np.nonzero`` loop — with the deferral decision delegated to
-        ``depth.mapping_gate``, the single home of the
-        ``min_mapping_bbox_area`` logic (Sec. 3.3).
+        One vectorized bbox/area pass over the instance map's labelled
+        pixels — no per-object ``np.nonzero`` loop and no [K, H, W]
+        presence array (tens of MB a 720p keyframe, whose allocation made
+        the detect's cost depend on the allocator's state) — with the
+        deferral decision delegated to ``depth.mapping_gate``, the single
+        home of the ``min_mapping_bbox_area`` logic (Sec. 3.3).
         Returns (class_ids [nd], masks_lo [nd, H/r, W/r] bool)."""
         kn = self.knobs
         r = kn.depth_downsampling_ratio
@@ -164,15 +217,26 @@ class MappingServer:
         if oids.size == 0:
             return cids[:0], np.zeros((0,) + inst_lo.shape, bool)
 
-        # full-res bbox areas in one pass: row/col presence -> extents
-        pres = frame.inst[None, :, :] == oids[:, None, None]   # [K, H, W]
+        # full-res bbox areas in one pass: each labelled pixel of a listed
+        # object counts toward its [K, H] row and [K, W] column presence
+        H, W = frame.inst.shape
+        flat = frame.inst.ravel()
+        pix = np.flatnonzero(flat)
+        lut = np.full(max(int(flat.max()), int(oids.max())) + 1, -1,
+                      np.int64)
+        lut[oids] = np.arange(len(oids))
+        k = lut[flat[pix]]
+        pix, k = pix[k >= 0], k[k >= 0]
+        K = len(oids)
 
-        def extent(present):                                   # [K, L] bool
+        def extent(idx, L):                                    # [K, L] bool
+            present = np.bincount(k * L + idx, minlength=K * L) \
+                .reshape(K, L) > 0
             first = present.argmax(axis=1)
-            last = present.shape[1] - 1 - present[:, ::-1].argmax(axis=1)
+            last = L - 1 - present[:, ::-1].argmax(axis=1)
             return last - first + 1
 
-        area = extent(pres.any(axis=2)) * extent(pres.any(axis=1))
+        area = extent(pix // W, H) * extent(pix % W, W)
         keep = np.asarray(depth_mod.mapping_gate(
             area, kn, frame_pixels=frame.inst.size))
         self.deferred += int((~keep).sum())
@@ -181,41 +245,58 @@ class MappingServer:
         masks_lo = inst_lo[None, :, :] == oids[:, None, None]
         return cids, masks_lo
 
+    def prepare(self, frame: Frame, classes: dict):
+        """Host side of one keyframe: detect, then pad to the fused
+        ingest's shapes.  Returns (``KeyframeInputs``, nd); the inputs are
+        None when nothing is detected."""
+        kn = self.knobs
+        r = kn.depth_downsampling_ratio
+        D = kn.max_detections_per_frame
+        cids, masks_lo = self._detect(frame, classes)
+        nd = len(cids)
+        if nd == 0:
+            return None, 0
+        pad_m = np.zeros((D,) + masks_lo.shape[1:], bool)
+        pad_m[:nd] = masks_lo
+        return KeyframeInputs(
+            depth=np.asarray(depth_mod.downsample_depth(frame.depth, r),
+                             np.float32),
+            masks=pad_m, intrinsics=np.asarray(frame.intrinsics, np.float32),
+            pose=np.asarray(frame.pose, np.float32),
+            cids=np.pad(cids, (0, D - nd)).astype(np.int32),
+            valid=np.arange(D) < nd), nd
+
+    def ingest_keyframe(self, store: ObjectStore, inputs: KeyframeInputs,
+                        key: jax.Array, index: int):
+        """Dispatch the fused ingest of one prepared keyframe (``index``:
+        the frame counter the transient prune reads) onto ``store``,
+        donated where ``donate`` is on.  Returns (store,
+        ``KeyframeRecord``), both still on the device."""
+        return self._ingest(store, *inputs, key, np.int32(index))
+
     # ------------------------------------------------------------------
     def process_frame(self, frame: Frame, classes: dict,
                       key: jax.Array) -> StageTimes:
         """Map one keyframe; returns per-stage wall times (Fig. 3)."""
         kn = self.knobs
-        r = kn.depth_downsampling_ratio
         D = kn.max_detections_per_frame
         times = StageTimes()
 
         t0 = time.perf_counter()
-        cids_np, masks_lo = self._detect(frame, classes)
+        inputs, nd = self.prepare(frame, classes)
         times.detect_ms = (time.perf_counter() - t0) * 1e3
-        nd = len(cids_np)
         if nd == 0:
             self.frame_count += 1
             times.record(self.mode)
             return times
-
-        depth_lo = jnp.asarray(depth_mod.downsample_depth(frame.depth, r))
-        intr = jnp.asarray(frame.intrinsics)
-        pose = jnp.asarray(frame.pose, jnp.float32)
-        pad_c = jnp.asarray(np.pad(cids_np, (0, D - nd)))
-        pad_m = np.zeros((D,) + masks_lo.shape[1:], bool)
-        pad_m[:nd] = masks_lo
-        valid = jnp.asarray(np.arange(D) < nd)
 
         # --- production path: ONE dispatch from masks to pruned store
         if self.mode == "semanticxr" and not self.instrument:
             t0 = time.perf_counter()
             with obs_span("pipeline.ingest_frame", cat="ingest",
                           nd=nd) as sp:
-                self.store = self._ingest(self.store, depth_lo,
-                                          jnp.asarray(pad_m), intr, pose,
-                                          pad_c, valid, key,
-                                          jnp.asarray(self.frame_count))
+                self.store, _ = self.ingest_keyframe(self.store, inputs, key,
+                                                     self.frame_count)
                 sp.fence(self.store.active)
             jax.block_until_ready(self.store.active)
             times.ingest_ms = (time.perf_counter() - t0) * 1e3
@@ -225,6 +306,14 @@ class MappingServer:
             return times
 
         # --- staged execution (B / B+P arms, and instrumented SD)
+        cids_np = inputs.cids[:nd]
+        masks_lo = inputs.masks[:nd]
+        depth_lo = jnp.asarray(inputs.depth)
+        intr = jnp.asarray(inputs.intrinsics)
+        pose = jnp.asarray(inputs.pose)
+        pad_c = jnp.asarray(inputs.cids)
+        pad_m = inputs.masks
+        valid = jnp.asarray(inputs.valid)
         # embedding (object-level parallelism: batch vs sequential)
         t0 = time.perf_counter()
         if self.mode == "baseline":

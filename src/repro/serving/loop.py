@@ -7,13 +7,19 @@ did bookkeeping and Python idled while the device computed.  This loop
 issues all three dispatch families against one consistent snapshot and
 lets JAX's async dispatch overlap them:
 
-- **Ingest** writes the NEXT store generation.  Overlapped mode donates
-  the dead back buffer of the ``SnapshotStore`` double buffer
-  (``core.store``): the scatter catches the two-tick-old buffer up
-  (pending + current delta) IN PLACE — O(changed rows) per tick instead
-  of the O(capacity) full-store copy the synchronous functional update
-  pays.  Queries keep reading the published front buffer, so a request
-  served mid-ingest sees exactly the pre-tick store, never a torn mix.
+- **Ingest** writes the NEXT store generation.  The ingest seam yields,
+  each tick, either one ``IngestDelta`` of pre-drawn rows or the
+  keyframes now due (``Keyframe``), which the loop maps one by one in due
+  order: host detect, then the mapper's one fused ``ingest_frame``
+  dispatch.  What the seam yields picks the path.  Overlapped mode
+  donates the dead back buffer of the ``SnapshotStore`` double buffer
+  (``core.store``) and catches it up IN PLACE before this tick's ingest
+  runs on it — by replaying the delta that produced the front, or by
+  copying from the front the rows the front's keyframes wrote (their
+  ``KeyframeRecord``s) — O(changed rows) per tick instead of the
+  O(capacity) full-store copy the synchronous functional update pays.
+  Queries keep reading the published front buffer, so a request served
+  mid-ingest sees exactly the pre-tick store, never a torn mix.
 - **Fleet sync** issues every dirty zone's ``_collect_fleet`` dispatch
   before materializing any packet (``SessionManager.collect_start`` /
   ``collect_finish``), with the [C, N] sync state donated.
@@ -35,6 +41,7 @@ throughput gap.
 """
 from __future__ import annotations
 
+import collections
 import functools
 import time
 from dataclasses import dataclass, field
@@ -44,9 +51,10 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.core.store import ObjectStore, SnapshotStore, deleted_mask
+from repro.core.store import (ObjectStore, SnapshotStore, copy_store,
+                              deleted_mask)
 from repro.obs import metrics as obs_metrics
-from repro.obs.trace import span as obs_span
+from repro.obs.trace import get_tracer, span as obs_span
 from repro.serving.batching import (BatchScheduler, PendingResult,
                                     make_query_step_fn)
 
@@ -115,6 +123,35 @@ def _apply_delta_donated(back: ObjectStore, cur: IngestDelta) -> ObjectStore:
     return _apply_delta_impl(back, cur)
 
 
+CATCH_UP_RECORDS = 4     # keyframe records per row-copy dispatch
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _copy_rows_donated(back: ObjectStore, front: ObjectStore,
+                       slots: tuple) -> ObjectStore:
+    """Catch the donated back buffer up to ``front`` where keyframes of
+    one tick wrote (``slots``: their records' [D] slot arrays; cap =
+    padding, dropped): the row columns (embedding, points) at those slots,
+    every per-slot scalar column whole — the prune can turn off any slot,
+    and those columns are a few bytes a slot."""
+    slots = jnp.concatenate(slots)
+    take = jnp.minimum(slots, front.ids.shape[0] - 1)
+    # copies, not the front's own buffers: jit would hand an unchanged
+    # input straight back, and the next ingest donates this generation
+    return jax.tree.map(jnp.copy, front)._replace(
+        embed=back.embed.at[slots].set(front.embed[take], mode="drop"),
+        points=back.points.at[slots].set(front.points[take], mode="drop"))
+
+
+class Keyframe(NamedTuple):
+    """One due keyframe, as the ingest seam hands it to the loop."""
+    frame: object          # repro.data.scenes.Frame: posed depth + instances
+    classes: dict          # object id -> class id (the detector stand-in)
+    key: jax.Array         # the embedder's noise key
+    mapper: int            # the headset that sent it
+    due: float | None = None   # perf_counter due time, when scheduled
+
+
 @dataclass
 class IngestStream:
     """Seeded per-tick delta schedule over a store's live region.
@@ -174,7 +211,8 @@ class ServingLoop:
     One tick, in both modes, does the same logical work against the same
     snapshot (the store published at the END of the previous tick):
 
-      1. issue the ingest scatter producing the next generation
+      1. issue the ingest producing the next generation: the seam's
+         ``IngestDelta`` scatter, or its due keyframes mapped in order
       2. mirror the snapshot into the fleet zones + collect dirty zones
       3. submit this tick's query arrivals; run scheduler steps
       4. publish the new generation; resolve query results
@@ -184,13 +222,18 @@ class ServingLoop:
     """
     server: object                    # FleetServer
     store: SnapshotStore
-    ingest: IngestStream
+    ingest: object                    # the seam: delta_at(t) -> an
+    #                                   IngestDelta or a list of Keyframes;
+    #                                   note_mapped(t, records) where
+    #                                   keyframes were mapped
     loadgen: object = None            # LoadGenerator | None
     overlap: bool = True
     batch_size: int = 16
     max_batches_per_tick: int = 2     # service capacity: backlog above this
     subscribe_radius: float = 6.0
     index: object = None              # ClusterIndex over the publish buffer
+    mapper: object = None             # MappingServer: maps the keyframes
+    #                                   the ingest seam yields
     # measured state
     tick_idx: int = 0
     results: dict = field(default_factory=dict)    # rid -> QueryResult (np)
@@ -211,6 +254,8 @@ class ServingLoop:
         self._deliverable = np.ones((self.server.n_clients,), bool)
         self._carry = {}          # overlap: last tick's unresolved results
         self._sync_started = []   # overlap: issued, unframed fleet collects
+        self._mapped = []         # dispatched keyframes, records unread
+        self._counts = collections.deque()   # tracing: read records' counts
 
     def enable_index(self, **kw) -> None:
         """Attach a cluster index maintained against the PUBLISH buffer:
@@ -221,19 +266,124 @@ class ServingLoop:
         self.__post_init__()       # rebuild the step fn with get_index
 
     # ------------------------------------------------------------------
-    def _issue_ingest(self, d: IngestDelta) -> ObjectStore:
+    def _issue_ingest(self, d) -> tuple:
+        """This tick's ingest, from what the seam yielded: an
+        ``IngestDelta`` or a list of due ``Keyframe``s.  Returns (the next
+        generation, what produced it from the front: the generation's
+        ``pending``)."""
         with obs_span("serving.ingest", cat="ingest", mode=self._mode) as sp:
-            if self.overlap:
-                back = self.store.take_back()
-                if self.store.pending is None:
-                    new = _apply_delta_donated(back, d)
-                else:
-                    new = _apply_delta2_donated(back, self.store.pending, d)
+            if isinstance(d, IngestDelta):
+                new, pending = self._apply_rows(d), d
             else:
-                new = apply_delta(self.store.front, d)
+                new, pending = self._map_keyframes(d)
+            if not self.overlap:
                 jax.block_until_ready(new.active)
             sp.fence(new.active)
-        return new
+        return new, pending
+
+    def _apply_rows(self, d: IngestDelta) -> ObjectStore:
+        if not self.overlap:
+            return apply_delta(self.store.front, d)
+        back = self.store.take_back()
+        if isinstance(self.store.pending, IngestDelta):
+            return _apply_delta2_donated(back, self.store.pending, d)
+        return _apply_delta_donated(self._catch_up(back), d)
+
+    def _catch_up(self, back: ObjectStore) -> ObjectStore:
+        """Bring the donated back buffer to the published front where the
+        front's keyframes wrote (the generation's ``pending`` holds their
+        records): one row copy for a tick of up to ``CATCH_UP_RECORDS``
+        keyframes."""
+        slots = [rec.slot for _, _, rec in self.store.pending or ()
+                 if rec is not None]
+        # CATCH_UP_RECORDS records a dispatch, the last repeated (a row
+        # copied twice is copied once): one width compiles, whatever a
+        # tick maps
+        R = CATCH_UP_RECORDS
+        for i in range(0, len(slots), R):
+            part = slots[i:i + R]
+            part += part[-1:] * (R - len(part))
+            back = _copy_rows_donated(back, self.store.front, tuple(part))
+        return back
+
+    def _map_keyframes(self, kfs: list) -> tuple:
+        """Map the due keyframes in order onto the next generation: the
+        caught-up back buffer (overlapped), or a copy of the front when
+        the mapper donates (sync: the front is read all tick).  The
+        transient prune counts keyframes over every mapper: the mapper's
+        ``frame_count``, where the single-mapper paper pipeline counts
+        one headset's frames."""
+        if self.overlap:
+            store = self._catch_up(self.store.take_back())
+        else:
+            store = self.store.front
+            if kfs and self.mapper.donate:
+                store = copy_store(store)
+        mapped = []
+        for kf in kfs:
+            store, rec = self._map_one(store, kf)
+            mapped.append(rec)
+        if mapped:
+            self._mapped.append((self.tick_idx, mapped))
+        return store, mapped
+
+    def _map_one(self, store: ObjectStore, kf: Keyframe) -> tuple:
+        """Detect and dispatch one keyframe.  Returns (store, (keyframe
+        index, kf, its ``KeyframeRecord`` on the device; None where
+        nothing was detected and nothing dispatched))."""
+        m = self.mapper
+        i = m.frame_count
+        m.frame_count += 1
+        with obs_span("mapping.detect", cat="ingest") as sp:
+            deferred = m.deferred
+            inputs, nd = m.prepare(kf.frame, kf.classes)
+            if sp.on:
+                sp.set(keyframe=i, mapper=kf.mapper, nd=nd,
+                       deferred=m.deferred - deferred)
+        if inputs is None:
+            return store, (i, kf, None)
+        with obs_span("mapping.ingest", cat="ingest") as sp:
+            if sp.on:
+                args = {"keyframe": i}
+                if kf.due is not None:
+                    args["wait_ms"] = round(
+                        (time.perf_counter() - kf.due) * 1e3, 3)
+                if self._counts:
+                    args.update(self._counts.popleft())
+                sp.set(**args)
+            store, rec = m.ingest_keyframe(store, inputs, kf.key, i)
+            for x in rec:
+                x.copy_to_host_async()
+        return store, (i, kf, rec)
+
+    def _read_records(self, upto: int) -> None:
+        """Bring the records of keyframes mapped at ticks <= ``upto`` to the
+        host, all of them with one read on copies started at dispatch, and
+        hand them to the ingest seam's ``note_mapped`` (a keyframe with no
+        detection, so no dispatch, has the record None)."""
+        done = [x for x in self._mapped if x[0] <= upto]
+        if not done:
+            return
+        self._mapped = [x for x in self._mapped if x[0] > upto]
+        with obs_span("host.fetch", cat="ingest", what="record") as sp:
+            host = jax.device_get([[rec for _, _, rec in mapped]
+                                   for _, mapped in done])
+            if sp.on:
+                sp.set(rows=sum(r is not None for rs in host for r in rs))
+        cap = self.store.front.ids.shape[0]
+        for (t, mapped), recs in zip(done, host):
+            out = [(i, kf, r) for (i, kf, _), r in zip(mapped, recs)]
+            self.ingest.note_mapped(t, out)
+            if get_tracer() is not None:
+                # the counts a later ``mapping.ingest`` span shows
+                for i, _, r in out:
+                    if r is None:
+                        continue
+                    ok = r.slot < cap
+                    self._counts.append(dict(
+                        counts_of=i, matched=int((ok & r.matched).sum()),
+                        inserted=int((ok & ~r.matched).sum()),
+                        pruned=int(r.n_pruned)))
 
     def _sync_tick(self, t: int) -> None:
         front = self.store.front
@@ -328,12 +478,12 @@ class ServingLoop:
         t = self.tick_idx
         wall0 = time.perf_counter()
         d = self.ingest.delta_at(t)
-        new = self._issue_ingest(d)
+        new, pending = self._issue_ingest(d)
         self._sync_tick(t)
         out = self._query_tick(t)
         with obs_span("serving.publish", cat="ingest"):
             if self.overlap:
-                self.store.publish(new, pending=d)
+                self.store.publish(new, pending=pending)
             else:
                 # synchronous mode never touched the back buffer: swap the
                 # front pointer only (the stale clone is never donated)
@@ -343,8 +493,12 @@ class ServingLoop:
             if self.index is not None:
                 # index maintenance rides the publish: update from the
                 # delta's touched slots against the NEW publish buffer
-                self.index.update_slots(self.store.front,
-                                        np.asarray(d.slots))
+                # (keyframes' slots reach the host a tick later: refresh)
+                if isinstance(d, IngestDelta):
+                    self.index.update_slots(self.store.front,
+                                            np.asarray(d.slots))
+                else:
+                    self.index.refresh(self.store.front)
         if self.overlap:
             # software pipelining: frame LAST tick's packets and resolve
             # LAST tick's queries now, carry this tick's — their device
@@ -358,8 +512,10 @@ class ServingLoop:
             self._finish_sync(t - 1)
             self._resolve(self._carry)
             self._carry = out
+            self._read_records(t - 1)
         else:
             self._resolve(out)
+            self._read_records(t)
         self.tick_idx += 1
         self.tick_ms.append((time.perf_counter() - wall0) * 1e3)
 
@@ -370,6 +526,7 @@ class ServingLoop:
         self._finish_sync(self.tick_idx)
         self._resolve(self._carry)
         self._carry = {}
+        self._read_records(self.tick_idx)
         while self.scheduler.waiting:
             out = self.scheduler.step()
             claim = time.perf_counter()

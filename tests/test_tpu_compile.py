@@ -147,3 +147,50 @@ def test_collect_fleet_compiles_at_c256(one_chip):
                              budget=32, points_budget=200,
                              knobs=kn).compile()
     _fits(c)
+
+
+def test_room_keyframe_ingest_donated_compiles(one_chip, monkeypatch):
+    """The shared room's served keyframe path at its widths (720p at
+    ratio 5, D=32, 2,000 points, E=512, 4,096 slots): the donated fused
+    ingest, Pallas branch, and the back buffer's row-copy catch-up."""
+    from repro.core.pipeline import MappingServer
+    from repro.core.store import store_from_knobs
+    from repro.perception.embedder import OracleEmbedder
+    from repro.serving.loop import CATCH_UP_RECORDS, _copy_rows_donated
+    kn = Knobs()
+    E = 512
+    store = jax.eval_shape(lambda: store_from_knobs(kn, E))
+    srv = MappingServer(knobs=kn, embedder=OracleEmbedder(embed_dim=E),
+                        store=store, donate=True)
+    D = kn.max_detections_per_frame
+    h, w = 720 // kn.depth_downsampling_ratio, 1280 // \
+        kn.depth_downsampling_ratio
+    args = (store,
+            jax.ShapeDtypeStruct((h, w), jnp.float32),
+            jax.ShapeDtypeStruct((D, h, w), jnp.bool_),
+            jax.ShapeDtypeStruct((4,), jnp.float32),
+            jax.ShapeDtypeStruct((4, 4), jnp.float32),
+            jax.ShapeDtypeStruct((D,), jnp.int32),
+            jax.ShapeDtypeStruct((D,), jnp.bool_),
+            jax.eval_shape(lambda: jax.random.key(0)),
+            jax.ShapeDtypeStruct((), jnp.int32))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    jax.clear_caches()
+    try:
+        c = srv._ingest.lower(*_spec(one_chip, args)).compile()
+        cu = _copy_rows_donated.lower(
+            *_spec(one_chip, (store, store, tuple(
+                jax.ShapeDtypeStruct((D,), jnp.int32)
+                for _ in range(CATCH_UP_RECORDS))))
+        ).compile()
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
+    assert _has_kernel(c), "ingest_frame did not take the Pallas branch"
+    # donated: the store's [cap, P, 3] cloud column is updated in place,
+    # so the executable holds one store, not two
+    m = c.memory_analysis()
+    cloud = store.points.size * 4
+    assert m.output_size_in_bytes - m.alias_size_in_bytes < cloud
+    _fits(c)
+    _fits(cu)
