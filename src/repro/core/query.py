@@ -143,7 +143,10 @@ def stack_queries(specs: list, pad_to: int | None = None) -> Query:
 
     All specs must share plan structure (same fields set, same static
     labels/zones/grid/k).  ``pad_to`` repeats the first spec to a fixed Q so
-    the downstream jit sees one shape per scheduler batch size.
+    the stack and the downstream sweep see one shape per scheduler batch
+    size.  The stack is one compiled dispatch (``_stack``) whatever Q and
+    the number of leaves; values, dtypes and weak types are those of
+    ``jnp.stack`` over the leaves.
     """
     if not specs:
         raise ValueError("stack_queries needs at least one spec")
@@ -160,9 +163,15 @@ def stack_queries(specs: list, pad_to: int | None = None) -> Query:
                              "(labels/zones/grid/k must agree)")
     if pad_to is not None and pad_to > len(specs):
         specs = specs + [first] * (pad_to - len(specs))
-    stacked = jax.tree.map(lambda *xs: jnp.stack(
-        [jnp.asarray(x) for x in xs]), *specs)
-    return replace(stacked, batched=True)
+    return _stack(tuple(specs))
+
+
+@jax.jit
+def _stack(specs: tuple) -> Query:
+    """Q unbatched specs -> one batched spec; keyed by Q, the leaf shapes
+    and dtypes, and the static plan."""
+    return replace(jax.tree.map(lambda *xs: jnp.stack(xs), *specs),
+                   batched=True)
 
 
 # ---------------------------------------------------------------------------

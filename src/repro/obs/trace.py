@@ -28,7 +28,8 @@ Design constraints (the reasons this file is small and boring):
 * **Args link the spans.**  Within a tick, the span that caused another
   contains it; across ticks, spans link by id in their args (request ids
   ``rids``, a collect's ``issue_tick``).  Call sites compute args only
-  when ``Span.on`` is true, so tracing off computes nothing.
+  when ``Span.on`` is true, so tracing off computes nothing.  A callee
+  annotates the span its caller opened through ``current_span()``.
 
 Export is the Chrome trace-event JSON format (chrome://tracing, Perfetto
 UI): complete events (``"ph": "X"``) with microsecond timestamps; nesting
@@ -43,7 +44,8 @@ from dataclasses import dataclass, field
 
 from jax.profiler import TraceAnnotation
 
-__all__ = ["Span", "Tracer", "get_tracer", "set_tracer", "span", "traced"]
+__all__ = ["Span", "Tracer", "current_span", "get_tracer", "set_tracer",
+           "span", "traced"]
 
 
 class _NullSpan:
@@ -81,8 +83,8 @@ class Span:
     on = True
 
     def __enter__(self):
-        self.tid = self.tracer._depth
-        self.tracer._depth += 1
+        self.tid = len(self.tracer._open)
+        self.tracer._open.append(self)
         self._ann = TraceAnnotation(self.name)
         self._ann.__enter__()
         self.t0 = time.perf_counter()
@@ -109,7 +111,7 @@ class Span:
             self._ann.set_metadata(**self.args)
         self._ann.__exit__(*exc)
         tr = self.tracer
-        tr._depth -= 1
+        tr._open.pop()
         tr.events.append((self.name, self.cat, self.t0, t1, self.tid,
                           self.args))
         return False
@@ -126,7 +128,8 @@ class Tracer:
     # would otherwise overlap, so the unfenced tracer stays in the <5%
     # overhead budget while the fenced one trades overhead for honesty.
     fenced: bool = False
-    _depth: int = 0
+    # the open spans, outermost first (``current_span`` reads the last)
+    _open: list = field(default_factory=list)
     _origin: float = field(default_factory=time.perf_counter)
 
     def span(self, name: str, cat: str = "", **args) -> Span:
@@ -134,7 +137,7 @@ class Tracer:
 
     def clear(self) -> None:
         self.events.clear()
-        self._depth = 0
+        self._open.clear()
         self._origin = time.perf_counter()
 
     def __len__(self) -> int:
@@ -203,6 +206,15 @@ def span(name: str, cat: str = "", **args):
     if t is None:
         return _NULL_SPAN
     return t.span(name, cat, **args)
+
+
+def current_span():
+    """The innermost open span of the process-wide tracer, or the no-op
+    span when tracing is off or no span is open."""
+    t = _TRACER
+    if t is None or not t._open:
+        return _NULL_SPAN
+    return t._open[-1]
 
 
 def traced(name: str | None = None, cat: str = ""):

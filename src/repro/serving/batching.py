@@ -18,7 +18,7 @@ from typing import Any, Callable
 
 import jax
 
-from repro.obs.trace import span as obs_span
+from repro.obs.trace import current_span, span as obs_span
 
 
 @dataclass(order=True)
@@ -181,12 +181,15 @@ def make_query_step_fn(get_map, *, k: int = 5, use_pallas: bool = False,
     ``Query(embed=..., k=k)``.
 
     Each engine step groups same-plan specs, stacks each group into ONE
-    batched spec (struct-of-arrays leading Q dim), and runs a SINGLE fused
+    batched spec (struct-of-arrays leading Q dim) with one compiled
+    dispatch (``stack_queries``), and runs a SINGLE fused
     predicate+score+top-k sweep per group over the map (the bias-kernel
     Pallas sweep when use_pallas — the embedding table streams through once
     for the whole batch, instead of Q full sweeps).  A uniform scheduler
     batch (the common case: every client sends the same plan shape) is
-    exactly one dispatch.
+    two dispatches on a flat target, the stack and the sweep; the enclosing
+    span (the scheduler's ``query.batch``) gets ``groups``, the number of
+    groups the step dispatched.
 
     ``get_map`` returns the current query target (ObjectStore, LocalMap, or
     ZoneShardedStore), re-read every step so a live mapping server can keep
@@ -223,6 +226,9 @@ def make_query_step_fn(get_map, *, k: int = 5, use_pallas: bool = False,
         for pos, s in enumerate(specs):
             key = (jax.tree.structure(s), s.tree_flatten()[1])
             groups.setdefault(key, []).append(pos)
+        sp = current_span()          # the scheduler's ``query.batch``
+        if sp.on:
+            sp.set(groups=len(groups))
         results: list = [None] * len(specs)
         for positions in groups.values():
             q = len(positions)

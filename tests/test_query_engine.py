@@ -9,6 +9,7 @@ wrapper equivalence + DeprecationWarning, Pallas-path parity, batched
 stacking, and the serving step-fn carrying Query specs.
 """
 import warnings
+from dataclasses import replace
 
 import numpy as np
 import jax
@@ -296,6 +297,77 @@ def test_stacked_batch_equals_singles():
     with pytest.raises(ValueError):
         stack_queries([Query(embed=st.embed[0], k=3),
                        Query(embed=st.embed[1], k=4)])
+
+
+def _eager_stack(specs, pad_to):
+    """The reference: one eager ``jnp.stack`` per leaf over the specs,
+    padded by repeating the first."""
+    specs = specs + [specs[0]] * (max(pad_to or 0, len(specs)) - len(specs))
+    return jax.tree.map(lambda *xs: jnp.stack([jnp.asarray(x)
+                                               for x in xs]), *specs)
+
+
+_LEAF_KINDS = {
+    # each builds spec i of a batch from leaves of one kind
+    "device": lambda st, i: Query(embed=st.embed[i], k=4),
+    "numpy": lambda st, i: Query(embed=np.asarray(st.embed[i], np.float64),
+                                 min_points=np.int64(2 + i % 3), k=4),
+    "python": lambda st, i: Query(embed=st.embed[i], sem_weight=1.0 + i,
+                                  min_obs=i % 2, since=0, k=4),
+    "near": lambda st, i: Query(embed=st.embed[i],
+                                near=(st.centroid[i], 3.0 + i),
+                                prox_weight=jnp.asarray(0.25), k=4),
+    "thresholds": lambda st, i: Query(
+        embed=st.embed[i], labels=(0, 1, 2, 3), min_points=jnp.asarray(i),
+        min_obs=jnp.asarray(1), since=jnp.asarray(i % 2),
+        aabb=(np.full(3, -3.0, np.float32), np.full(3, 3.0, np.float32)),
+        k=4),
+}
+
+
+@pytest.mark.parametrize("pad_to", [8, 5, None], ids=["pad-above-q",
+                                                      "pad-at-q", "no-pad"])
+@pytest.mark.parametrize("kind", sorted(_LEAF_KINDS))
+def test_compiled_stack_equals_eager_stack(kind, pad_to):
+    """``stack_queries`` (one compiled dispatch) gives the eager stack's
+    batched spec leaf for leaf: values, dtype, weak type and shape, with
+    the same static plan and ``batched`` set; a batch that needs no new
+    shape reuses the executable."""
+    from repro.core.query import _stack
+    st = _store(40, 7)
+    specs = [_LEAF_KINDS[kind](st, i) for i in range(5)]
+    got = stack_queries(specs, pad_to=pad_to)
+    want = _eager_stack(specs, pad_to)
+    assert got.batched and not want.batched
+    assert got.tree_flatten()[1] == \
+        replace(want, batched=True).tree_flatten()[1]
+    assert jax.tree.structure(got) == \
+        jax.tree.structure(replace(want, batched=True))
+    gl, wl = jax.tree.leaves(got), jax.tree.leaves(want)
+    assert len(gl) == len(wl) > 0
+    for g, w in zip(gl, wl):
+        assert g.shape == w.shape and g.shape[0] == (pad_to or 5)
+        assert g.dtype == w.dtype and g.weak_type == w.weak_type
+        assert np.asarray(g).tobytes() == np.asarray(w).tobytes()
+    n = _stack._cache_size()
+    stack_queries([_LEAF_KINDS[kind](st, 5 + i) for i in range(3)],
+                  pad_to=pad_to or 5)
+    assert _stack._cache_size() == n
+
+
+@pytest.mark.parametrize("case", ["empty", "batched", "all-static",
+                                  "mismatched-plan"])
+def test_stack_queries_refusals(case):
+    st = _store(40, 3)
+    specs = {
+        "empty": [],
+        "batched": [Query(embed=st.embed[:2], batched=True, k=3)],
+        "all-static": [Query(labels=(1,), k=3), Query(labels=(1,), k=3)],
+        "mismatched-plan": [Query(embed=st.embed[0], k=3),
+                            Query(embed=st.embed[1], k=4)],
+    }[case]
+    with pytest.raises(ValueError):
+        stack_queries(specs, pad_to=4)
 
 
 def test_zone_pruning_before_dispatch():

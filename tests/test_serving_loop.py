@@ -580,6 +580,64 @@ def test_pending_rows_equal_blocking_rows(legacy):
             x.tobytes() == y.tobytes() for x, y in zip(got, ref))
 
 
+@pytest.mark.parametrize("block", [True, False], ids=["blocking", "pending"])
+def test_scheduler_batches_stack_in_one_compile(block):
+    """Twenty scheduler steps of fills 1-16 at ``pad_to`` 16 compile the
+    stack once; each ``query.batch`` span says how many groups the step
+    dispatched (one plan: 1; a step of two plans: 2); every row is byte
+    for byte the per-spec ``execute_query`` answer."""
+    from repro.core.query import _stack
+    from repro.obs import Tracer
+    from repro.obs.trace import set_tracer
+    from repro.serving.batching import (BatchScheduler, PendingResult,
+                                        make_query_step_fn)
+    store = _store()
+    cent = np.asarray(store.centroid)
+    rng = np.random.default_rng(5)
+
+    def spec(i, near=True):
+        e = rng.normal(size=E).astype(np.float32)
+        e /= np.linalg.norm(e)
+        if not near:
+            return Query(embed=jnp.asarray(e), k=6)
+        return Query(embed=jnp.asarray(e),
+                     near=(jnp.asarray(cent[i % NLIVE]),
+                           jnp.asarray(3.0 + i % 4, jnp.float32)),
+                     min_points=jnp.asarray(2), k=6)
+
+    sched = BatchScheduler(batch_size=16, step_fn=make_query_step_fn(
+        lambda: store, pad_to=16, block=block))
+    fills = [1, 16] + list(rng.integers(1, 17, size=18))
+    tr = Tracer()
+    _stack.clear_cache()
+    prev = set_tracer(tr)
+    try:
+        specs, served = {}, {}
+        for f in fills:
+            for i in range(int(f)):
+                s_ = spec(len(specs))
+                specs[sched.submit(s_)] = s_
+            served.update(sched.step())
+        assert _stack._cache_size() == 1
+        mixed = [spec(0), spec(1, near=False), spec(2)]
+        for s_ in mixed:
+            specs[sched.submit(s_)] = s_
+        served.update(sched.step())
+    finally:
+        set_tracer(prev)
+    groups = [a["groups"] for a in _args(tr, "query.batch")]
+    assert groups == [1] * len(fills) + [2]
+    assert set(served) == set(specs)
+    for rid, res in served.items():
+        assert isinstance(res, PendingResult) != block
+        got = res.resolve() if not block else res
+        want = execute_query(store, specs[rid])
+        for x, y in zip(got, want):
+            y = np.asarray(y)
+            assert x.dtype == y.dtype and x.shape == y.shape == (6,)
+            assert x.tobytes() == y.tobytes()
+
+
 def test_query_wait_is_step_start_minus_enqueue(traced_run):
     tr, _, reqs, _, _, _ = traced_run
     batches = [a for a in _args(tr, "query.batch") if "rids" in a]
