@@ -15,7 +15,7 @@ sys.path.insert(0, str(ROOT))
 
 from benchmarks import (downstream_bw, fault_tolerance, fleet_scale,
                         ingest_tick, local_map_scale, mapping_latency,
-                        power_model, query_engine, query_latency, roofline,
+                        power_model, query_engine, query_latency,
                         scenario_suite, serving_loop, upstream_bw)
 
 SUITES = {
@@ -25,7 +25,6 @@ SUITES = {
     "fig6_downstream": downstream_bw.run,
     "tab5_upstream": upstream_bw.run,
     "fig7_power": power_model.run,
-    "roofline": roofline.run,
     "ingest_tick": ingest_tick.run,
     "fleet_scale": fleet_scale.run,
     "serving_loop": serving_loop.run,

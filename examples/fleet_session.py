@@ -25,7 +25,8 @@ import numpy as np
 from repro.core import Knobs, MappingServer
 from repro.data.scenes import make_scene, scene_stream
 from repro.perception.embedder import OracleEmbedder
-from repro.server import FleetSimulator, Query, ZoneGrid
+from repro.server import Query, ZoneGrid
+from repro.sim import FleetSimulator
 from repro.compile_cache import enable_compile_cache
 
 

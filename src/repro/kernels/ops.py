@@ -15,15 +15,15 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import flash_attention as _fa
 from repro.kernels import lift_compact as _lc
 from repro.kernels import pairwise as _pw
 from repro.kernels import query_topk as _qt
 
 
 def _interpret() -> bool:
-    """Shared backend key: every kernel entry point resolves its
-    ``interpret=None`` default through this helper."""
+    """Shared backend key: the query top-k and nearest-distance kernels
+    resolve their ``interpret=None`` default through this helper
+    (``lift_compact`` keys off the backend itself)."""
     return jax.default_backend() != "tpu"
 
 
@@ -95,10 +95,3 @@ def nearest_dist(a, b, b_valid):
         b = jnp.pad(b, ((0, 0), (0, padd)))
     return _pw.nearest_dist_pallas(a, b, b_valid, interpret=_interpret())
 
-
-@partial(jax.jit, static_argnames=("causal", "window", "softcap"))
-def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
-                    softcap: float = 0.0):
-    return _fa.flash_attention_pallas(q, k, v, causal=causal, window=window,
-                                      softcap=softcap,
-                                      interpret=_interpret())

@@ -60,23 +60,3 @@ def nearest_dist_ref(a: jax.Array, b: jax.Array, b_valid: jax.Array):
     d2 = jnp.where(b_valid[None, :], d2, jnp.inf)
     return jnp.min(d2, axis=1)
 
-
-def flash_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                        causal: bool = True, window: int = 0,
-                        softcap: float = 0.0):
-    """q,k,v: [H, S, dh] (single batch slice) -> [H, S, dh]."""
-    H, S, dh = q.shape
-    s = jnp.einsum("hqd,hkd->hqk", q.astype(jnp.float32),
-                   k.astype(jnp.float32)) * dh ** -0.5
-    if softcap:
-        s = jnp.tanh(s / softcap) * softcap
-    qpos = jnp.arange(S)[:, None]
-    kpos = jnp.arange(S)[None, :]
-    mask = jnp.ones((S, S), bool)
-    if causal:
-        mask &= kpos <= qpos
-    if window:
-        mask &= qpos - kpos < window
-    s = jnp.where(mask[None], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("hqk,hkd->hqd", p, v.astype(jnp.float32)).astype(q.dtype)

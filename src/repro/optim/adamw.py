@@ -1,8 +1,7 @@
 """AdamW with global-norm clipping and mixed-precision master params.
 
 Model params may live in bf16; the optimizer keeps an fp32 master copy plus
-fp32 moments.  Under the production mesh the master/moment trees are
-additionally ZeRO-1 sharded over the data axis (see distributed/sharding.py).
+fp32 moments.
 """
 from __future__ import annotations
 
@@ -11,8 +10,6 @@ from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
-
-from repro.models import common as cm
 
 
 @dataclass(frozen=True)
@@ -33,11 +30,6 @@ class OptState(NamedTuple):
     master: Any       # fp32 copy of params
     m: Any
     v: Any
-
-
-def opt_state_specs(param_specs, ocfg: AdamWConfig) -> OptState:
-    f32 = jax.tree.map(lambda l: cm.spec(l.shape, jnp.float32), param_specs)
-    return OptState(step=cm.spec((), jnp.int32), master=f32, m=f32, v=f32)
 
 
 def init_opt_state(params, ocfg: AdamWConfig) -> OptState:
@@ -61,8 +53,7 @@ def global_norm(tree) -> jax.Array:
                         for g in jax.tree.leaves(tree)))
 
 
-_NO_DECAY_SUFFIXES = ("scale", "bias", "A_log", "D", "dt_bias", "mix_mu",
-                      "decay_base", "bonus_u")
+_NO_DECAY_SUFFIXES = ("scale", "bias")
 
 
 def _decay_mask(path) -> bool:

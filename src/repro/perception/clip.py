@@ -8,6 +8,7 @@ OracleEmbedder remains the controlled backend for system benchmarks.
 """
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
 
 import numpy as np
@@ -16,7 +17,6 @@ import jax.numpy as jnp
 
 from repro.data.scenes import CLASS_NAMES, N_CLASSES
 from repro.data.tokens import VOCAB, VOCAB_SIZE
-from repro.models import common as cm
 
 CROP = 16  # depth-crop resolution fed to the object tower
 
@@ -32,20 +32,34 @@ class ClipConfig:
 def clip_param_specs(ccfg: ClipConfig) -> dict:
     w, e = ccfg.width, ccfg.embed_dim
     specs: dict = {
-        "obj_in": cm.spec((CROP * CROP + 4, w), jnp.float32),
-        "txt_embed": cm.spec((VOCAB_SIZE, w), jnp.float32),
-        "logit_scale": cm.spec((), jnp.float32),
+        "obj_in": jax.ShapeDtypeStruct((CROP * CROP + 4, w), jnp.float32),
+        "txt_embed": jax.ShapeDtypeStruct((VOCAB_SIZE, w), jnp.float32),
+        "logit_scale": jax.ShapeDtypeStruct((), jnp.float32),
     }
     for t in ("obj", "txt"):
         for i in range(ccfg.depth):
-            specs[f"{t}_w{i}"] = cm.spec((w, w), jnp.float32)
-            specs[f"{t}_b{i}_bias"] = cm.spec((w,), jnp.float32)
-        specs[f"{t}_out"] = cm.spec((w, e), jnp.float32)
+            specs[f"{t}_w{i}"] = jax.ShapeDtypeStruct((w, w), jnp.float32)
+            specs[f"{t}_b{i}_bias"] = jax.ShapeDtypeStruct((w,), jnp.float32)
+        specs[f"{t}_out"] = jax.ShapeDtypeStruct((w, e), jnp.float32)
     return specs
 
 
+def _leaf_init(key, name: str, shape, dtype):
+    """Biases and the logit scale start at zero; matrices truncated-normal
+    over sqrt(fan_in)."""
+    if name.endswith("bias") or name == "logit_scale":
+        return jnp.zeros(shape, dtype)
+    std = 1.0 / math.sqrt(max(shape[-2], 1))
+    w = jax.random.truncated_normal(key, -2.0, 2.0, shape, jnp.float32) * std
+    return w.astype(dtype)
+
+
 def init_clip_params(ccfg: ClipConfig, key: jax.Array):
-    p = cm.init_from_specs(key, clip_param_specs(ccfg))
+    specs = clip_param_specs(ccfg)
+    names = sorted(specs)        # the flatten order of a dict pytree
+    keys = jax.random.split(key, len(names))
+    p = {n: _leaf_init(k, n, specs[n].shape, specs[n].dtype)
+         for n, k in zip(names, keys)}
     p["logit_scale"] = jnp.log(1.0 / ccfg.temperature_init)
     return p
 

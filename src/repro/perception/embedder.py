@@ -8,8 +8,8 @@ Two interchangeable backends behind one interface:
   margins), which is exactly what the paper's system evaluation needs:
   quality differences must come from SYSTEM choices (downsampling, deferral),
   not model noise.
-* ClipEmbedder — a real two-tower (object-crop tower + text tower) built
-  from the repro model zoo and trained contrastively in
+* ClipEmbedder — a real two-tower (object-crop tower + text tower,
+  perception/clip.py) trained contrastively in
   examples/train_perception.py.  Used by the end-to-end demo.
 
 Both produce unit-norm [E] embeddings for observations and queries.

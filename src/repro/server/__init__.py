@@ -13,7 +13,7 @@ Three layers:
 ``zones``    ZoneShardedStore — objects partitioned into spatial zones
              (grid over the room plane), each zone an independent
              `core.store.ObjectStore` shard, placeable on mesh devices via
-             `distributed.sharding.zone_shard_devices`.  Clients subscribe
+             `zones.zone_shard_devices`.  Clients subscribe
              to the zones their pose overlaps; downstream work scales with
              per-client zone *changes*, not fleet size.
 
@@ -25,13 +25,10 @@ Three layers:
              byte-identical to the single-device path
              (`FleetServer(n_session_shards=S)`).
 
-``fleet``    FleetServer (zones x sessions composition) and FleetSimulator —
-             tens-to-hundreds of simulated clients with heterogeneous
-             `core.runtime.NetworkModel`s (mixed RTTs, staggered outages,
-             join/leave churn), sharing the single-client per-tick step
-             (`core.runtime.ClientSession`) and routing cross-client queries
-             through `serving.batching.BatchScheduler` +
-             `core.query` multi-query top-k.
+``fleet``    FleetServer — the zones x sessions composition and its
+             control plane (sync epochs, ack routing, tombstone
+             retirement).  The simulated client fleet that drives it
+             lives in `sim.fleet`.
 
 Queries against the fleet store go through the declarative engine
 (`core.query`, re-exported here): `FleetServer.query(Query(...))` compiles
@@ -49,4 +46,4 @@ from repro.server.session import (FleetBatch, FleetPacket, FleetSync,
 from repro.server.zones import ZoneGrid, ZoneShardedStore
 from repro.server.mesh import (ClientRoster, MeshFleetPacket,
                                MeshSessionTier)
-from repro.server.fleet import FleetServer, FleetSimulator, SimClient
+from repro.server.fleet import FleetServer

@@ -9,7 +9,7 @@ the host bookkeeping on one process; the ROADMAP's C≥4096 tier wants both
 partitioned.  ``MeshSessionTier`` shards the CLIENT axis: S plain
 SessionManager parts, part s owning rows for the clients a ``ClientRoster``
 homes there (subscribed-zone affinity via
-``distributed.sharding.client_shard_affinity``, round-robin before poses
+``client_shard_affinity``, round-robin before poses
 exist).  Each part is placed on its own mesh device (``place_on``), so a
 part's vmapped ``_collect_fleet`` gathers run where its clients' zone
 stores live.
@@ -42,6 +42,7 @@ from repro.core.knobs import Knobs
 from repro.core.store import ObjectStore
 from repro.core.updates import UpdatePacket
 from repro.server.session import SessionManager
+from repro.server.zones import zone_shard_devices
 
 
 # ---------------------------------------------------------------------------
@@ -89,8 +90,7 @@ class ClientRoster:
     def from_affinity(cls, subscribed: np.ndarray, n_shards: int,
                       zone_shards=None) -> "ClientRoster":
         """Partition by subscribed-zone affinity (majority vote over the
-        zones' shard placement; see distributed.sharding)."""
-        from repro.distributed.sharding import client_shard_affinity
+        zones' shard placement; see ``client_shard_affinity``)."""
         return cls(assign=client_shard_affinity(subscribed, n_shards,
                                                 zone_shards),
                    n_shards=n_shards)
@@ -209,7 +209,6 @@ class MeshSessionTier:
         device (round-robin, same placement rule as zone_shard_devices).
         Host-side per-client state stays with the part object — on a real
         multi-host mesh that state lives in the shard's server process."""
-        from repro.distributed.sharding import zone_shard_devices
         self.devices = zone_shard_devices(mesh, self.n_shards)
         for s, p in self._live():
             p.sync = jax.device_put(p.sync, self.devices[s])
@@ -348,3 +347,36 @@ class MeshSessionTier:
         return self.collect_finish(self.collect_start(
             store, deliverable=deliverable, zone=zone, epoch=epoch,
             fresh=fresh, now=now))
+
+
+def client_shard_affinity(subscribed: np.ndarray, n_shards: int,
+                          zone_shards: np.ndarray | None = None) -> np.ndarray:
+    """Assign each client to a session shard by subscribed-zone affinity.
+
+    ``subscribed`` is the fleet's [C, Z] zone-subscription matrix and
+    ``zone_shards`` [Z] maps each spatial zone to the session shard whose
+    device holds that zone's store arrays (``zone_shard_devices``
+    placement: defaults to z % n_shards).  A client is homed on the shard
+    that owns the MOST of its subscribed zones — majority vote, lowest
+    shard id on ties — so the sharded session tier's sync gathers read
+    zone stores resident on the same device.  Clients with no
+    subscriptions yet fall back to round-robin (c % n_shards), which
+    keeps the partition load-balanced before the first pose arrives.
+
+    Returns [C] int32 shard assignment.  The assignment is computed at
+    tier construction; live re-homing of a moving client is a control-
+    plane migration (ROADMAP) and is not done per pose update.
+    """
+    subscribed = np.asarray(subscribed, bool)
+    C, Z = subscribed.shape
+    if zone_shards is None:
+        zone_shards = np.arange(Z) % n_shards
+    zone_shards = np.asarray(zone_shards)
+    # [C, S] votes: how many of client c's zones live on shard s
+    votes = np.zeros((C, n_shards), np.int64)
+    for s in range(n_shards):
+        votes[:, s] = subscribed[:, zone_shards == s].sum(axis=1)
+    assign = votes.argmax(axis=1).astype(np.int32)   # argmax = lowest tie
+    none = ~subscribed.any(axis=1)
+    assign[none] = (np.arange(C)[none] % n_shards).astype(np.int32)
+    return assign

@@ -16,7 +16,7 @@ host-side; freed shard slots are reported so the per-zone SessionManager
 can forget stale sync versions before the slot is reused.
 
 ``place_on(mesh)`` places the shards round-robin on a mesh's devices
-(`distributed.sharding.zone_shard_devices`); refreshes then gather the
+(`zone_shard_devices`); refreshes then gather the
 changed rows on the global store's device and ship only those.
 """
 from __future__ import annotations
@@ -26,6 +26,7 @@ from dataclasses import dataclass, field
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.sharding import Mesh
 
 from repro.core.knobs import Knobs
 from repro.core.store import ObjectStore, deleted_mask, init_store
@@ -336,7 +337,15 @@ class ZoneShardedStore:
     def place_on(self, mesh) -> None:
         """Place shard z on mesh device z % ndev; later refreshes ship
         only the changed rows to it (``_gather_rows``)."""
-        from repro.distributed.sharding import zone_shard_devices
         self.devices = zone_shard_devices(mesh, len(self.zones))
         self.zones = [jax.device_put(zone, d)
                       for zone, d in zip(self.zones, self.devices)]
+
+
+def zone_shard_devices(mesh: Mesh, n_zones: int) -> list:
+    """Round-robin device placement for the fleet server's spatial zone
+    shards (server/zones.py): zone z lives on mesh device z % ndev, so
+    per-zone sync collects and queries run where the shard's arrays live.
+    On the 1-device container every zone maps to the same device (no-op)."""
+    devs = list(mesh.devices.flat)
+    return [devs[z % len(devs)] for z in range(n_zones)]

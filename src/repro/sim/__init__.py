@@ -8,7 +8,7 @@ drops, bounded memory at tens of thousands of objects, downstream bandwidth
 workload: a seeded declarative ``Scenario`` (object lifecycle events, user
 trajectories, network traces, fleet churn, knob schedule) driven by one
 ``ScenarioEngine`` loop that subsumes the ad-hoc session drivers
-(examples/network_drop_session.py, server.fleet.FleetSimulator are thin
+(examples/network_drop_session.py, sim.fleet.FleetSimulator are thin
 wrappers) and emits a structured, bit-replayable ``MetricsLog``.
 """
 from repro.core.runtime import FaultModel
@@ -17,3 +17,4 @@ from repro.sim.scenario import (ClientSpec, CrashEvent, GridSpec, KnobEvent,
                                 Scenario, churn_scenario)
 from repro.sim.world import WorldState
 from repro.sim.engine import MetricsLog, ScenarioEngine, run_scenario
+from repro.sim.fleet import FleetSimulator, SimClient

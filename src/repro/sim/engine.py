@@ -1,7 +1,7 @@
 """The discrete-event session loop: one engine for every scenario.
 
 Subsumes the two ad-hoc drivers (examples/network_drop_session.py and
-server.fleet.FleetSimulator are thin wrappers): per tick it applies the
+sim.fleet.FleetSimulator are thin wrappers): per tick it applies the
 scenario's knob + object events to the world (or steps a mapping frontend
 over rendered frames), mirrors the store into the zone-sharded fleet
 server, advances client churn/poses, runs ONE vmapped fleet collect,

@@ -11,8 +11,9 @@ from repro.core.local_map import compute_priority
 from repro.core.runtime import ClientSession, DeviceClient, NetworkModel
 from repro.core.store import synthetic_store
 from repro.core.updates import collect_updates, init_sync, update_nbytes
-from repro.server import (FleetServer, FleetSimulator, SessionManager,
-                          ZoneGrid, ZoneShardedStore)
+from repro.server import (FleetServer, SessionManager, ZoneGrid,
+                          ZoneShardedStore)
+from repro.sim import FleetSimulator
 
 E = 32
 KN = Knobs(server_capacity=64, client_capacity=64,
@@ -551,7 +552,7 @@ def test_mesh_fleet_server_end_to_end_byte_identity():
 def test_client_shard_affinity():
     """Zone-affinity partition: a client lands on the shard holding the
     majority of its subscribed zones; unsubscribed clients round-robin."""
-    from repro.distributed.sharding import client_shard_affinity
+    from repro.server.mesh import client_shard_affinity
     subs = np.zeros((4, 8), bool)
     subs[0, [0, 2, 4]] = True          # zones 0,2,4 -> shard 0 under z%2
     subs[1, [1, 3]] = True             # -> shard 1

@@ -92,57 +92,3 @@ def test_nearest_dist(m, n, d):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-4)
 
-
-@pytest.mark.parametrize("h,s,dh,causal,window,softcap,dtype", [
-    (2, 128, 64, True, 0, 0.0, jnp.float32),
-    (4, 256, 64, True, 64, 0.0, jnp.float32),
-    (2, 200, 128, True, 0, 50.0, jnp.float32),
-    (1, 128, 64, False, 0, 0.0, jnp.float32),
-    (2, 256, 64, True, 0, 0.0, jnp.bfloat16),
-])
-def test_flash_attention(h, s, dh, causal, window, softcap, dtype):
-    kq, kk, kv = jax.random.split(jax.random.key(s + h), 3)
-    q = jax.random.normal(kq, (h, s, dh), dtype)
-    k = jax.random.normal(kk, (h, s, dh), dtype)
-    v = jax.random.normal(kv, (h, s, dh), dtype)
-    got = ops.flash_attention(q, k, v, causal=causal, window=window,
-                              softcap=softcap)
-    want = ref.flash_attention_ref(q, k, v, causal=causal, window=window,
-                                   softcap=softcap)
-    rtol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(want, np.float32),
-                               rtol=rtol, atol=rtol)
-
-
-def test_flash_attention_matches_model_blocked():
-    """Kernel vs the model-side jnp blocked attention (same math path)."""
-    from repro.models.attention import blocked_attention
-    kq, kk, kv = jax.random.split(jax.random.key(0), 3)
-    B, S, H, dh = 2, 192, 4, 64
-    q = jax.random.normal(kq, (B, S, H, dh), jnp.float32)
-    k = jax.random.normal(kk, (B, S, H, dh), jnp.float32)
-    v = jax.random.normal(kv, (B, S, H, dh), jnp.float32)
-    want = blocked_attention(q, k, v, causal=True, q_chunk=64, k_chunk=64)
-    got = jax.vmap(lambda qq, kk_, vv: ops.flash_attention(
-        qq.transpose(1, 0, 2), kk_.transpose(1, 0, 2),
-        vv.transpose(1, 0, 2)).transpose(1, 0, 2))(q, k, v)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-4, atol=2e-4)
-
-
-def test_pruned_attention_matches_full():
-    """Tile-pruned blocked attention == full sweep (causal + SWA)."""
-    from repro.models.attention import blocked_attention
-    kq, kk, kv = jax.random.split(jax.random.key(3), 3)
-    B, S, H, dh = 2, 384, 4, 32
-    q = jax.random.normal(kq, (B, S, H, dh), jnp.float32)
-    k = jax.random.normal(kk, (B, S, H, dh), jnp.float32)
-    v = jax.random.normal(kv, (B, S, H, dh), jnp.float32)
-    for window in (0, 128):
-        full = blocked_attention(q, k, v, causal=True, window=window,
-                                 q_chunk=128, k_chunk=64, prune=False)
-        pruned = blocked_attention(q, k, v, causal=True, window=window,
-                                   q_chunk=128, k_chunk=64, prune=True)
-        np.testing.assert_allclose(np.asarray(pruned), np.asarray(full),
-                                   rtol=1e-5, atol=1e-5)
