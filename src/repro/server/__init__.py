@@ -13,7 +13,9 @@ Three layers:
 ``zones``    ZoneShardedStore — objects partitioned into spatial zones
              (grid over the room plane), each zone an independent
              `core.store.ObjectStore` shard, placeable on mesh devices via
-             `zones.zone_shard_devices`.  Clients subscribe
+             `zones.zone_shard_devices`.  Each shard keeps its rows'
+             client-resolution clouds (`session.ClientClouds`), written
+             with the rows, for the collect to gather.  Clients subscribe
              to the zones their pose overlaps; downstream work scales with
              per-client zone *changes*, not fleet size.
 

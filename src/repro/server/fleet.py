@@ -14,6 +14,7 @@ import numpy as np
 from repro.core.knobs import Knobs
 from repro.core.query import Query, QueryResult, compile_query
 from repro.core.store import ObjectStore
+from repro.core.updates import class_budget_table
 from repro.obs.trace import span as obs_span
 from repro.server.session import FleetPacket, SessionManager
 from repro.server.zones import ZoneGrid, ZoneShardedStore
@@ -94,6 +95,12 @@ class FleetServer:
                         donate=self.donate,
                         subscribed=np.zeros((self.n_clients,), bool))
                     for _ in range(self.grid.n_zones)]
+        # the sessions ship the client clouds the zone mirror keeps, so
+        # both must downsample to the same width and class budgets
+        want = _client_points(self.zoned.knobs)
+        if any(_client_points(s.knobs) != want for s in self.sessions):
+            raise ValueError("sessions and the zone store disagree on the "
+                             "client point budgets")
         if self.subscribed is None:
             self.subscribed = np.zeros((self.n_clients, self.grid.n_zones),
                                        bool)
@@ -315,7 +322,8 @@ class FleetServer:
                   if sess.dirty and (sess.subscribed & deliverable).any()]
             out = [(z, self.sessions[z].collect(
                 self.zoned.zones[z], deliverable=deliverable, zone=z,
-                epoch=self.epoch, fresh=self.epoch_fresh, now=tick))
+                epoch=self.epoch, fresh=self.epoch_fresh, now=tick,
+                clouds=self.zoned.clouds[z]))
                 for z in zs]
             sp.set(zones_collected=len(out))
         return out
@@ -344,7 +352,8 @@ class FleetServer:
         with obs_span("fleet.tick_start", cat="sync") as sp:
             started = [(z, self.sessions[z].collect_start(
                 self.zoned.zones[z], deliverable=deliverable, zone=z,
-                epoch=self.epoch, fresh=self.epoch_fresh, now=tick))
+                epoch=self.epoch, fresh=self.epoch_fresh, now=tick,
+                clouds=self.zoned.clouds[z]))
                 for z, sess in enumerate(self.sessions)
                 if sess.dirty and (sess.subscribed & deliverable).any()]
             sp.set(zones_collected=len(started))
@@ -376,3 +385,10 @@ class FleetServer:
         are global ``zone * zone_capacity + shard_slot`` rows."""
         return compile_query(spec, self.zoned,
                              use_pallas=use_pallas)(self.zoned)
+
+
+def _client_points(knobs: Knobs) -> tuple:
+    """What a client cloud is cut to: the buffer width and the per-class
+    budgets."""
+    return (knobs.max_object_points_client,
+            class_budget_table(knobs).tobytes())

@@ -41,7 +41,7 @@ import jax
 from repro.core.knobs import Knobs
 from repro.core.store import ObjectStore
 from repro.core.updates import UpdatePacket
-from repro.server.session import SessionManager
+from repro.server.session import ClientClouds, SessionManager
 from repro.server.zones import zone_shard_devices
 
 
@@ -281,7 +281,8 @@ class MeshSessionTier:
                       deliverable: np.ndarray | None = None, zone: int = 0,
                       epoch: np.ndarray | None = None,
                       fresh: np.ndarray | None = None,
-                      now: int | None = None) -> _MeshPending:
+                      now: int | None = None,
+                      clouds: ClientClouds | None = None) -> _MeshPending:
         """Issue every shard's collect dispatch (the shard devices run
         concurrently under jax async dispatch; on the 1-device container
         the dispatches queue).  Every live part is dispatched whenever the
@@ -290,12 +291,12 @@ class MeshSessionTier:
         pend = [None] * self.n_shards
         for s, p in self._live():
             m = self.roster.members[s]
-            st = store
+            st, cl = store, clouds
             if self.devices[s] is not None:
                 # placed tier: the shard reads a device-local view of the
                 # store (no-op when the placement already matches, as on
                 # the 1-device container)
-                st = jax.device_put(store, self.devices[s])
+                st, cl = jax.device_put((store, clouds), self.devices[s])
             pend[s] = p.collect_start(
                 st,
                 deliverable=None if deliverable is None
@@ -303,7 +304,7 @@ class MeshSessionTier:
                 zone=zone,
                 epoch=None if epoch is None else np.asarray(epoch)[m],
                 fresh=None if fresh is None else np.asarray(fresh)[m],
-                now=now)
+                now=now, clouds=cl)
         return _MeshPending(pend)
 
     def collect_finish(self, pending: _MeshPending) -> MeshFleetPacket:
@@ -343,10 +344,11 @@ class MeshSessionTier:
                 deliverable: np.ndarray | None = None, zone: int = 0,
                 epoch: np.ndarray | None = None,
                 fresh: np.ndarray | None = None,
-                now: int | None = None) -> MeshFleetPacket:
+                now: int | None = None,
+                clouds: ClientClouds | None = None) -> MeshFleetPacket:
         return self.collect_finish(self.collect_start(
             store, deliverable=deliverable, zone=zone, epoch=epoch,
-            fresh=fresh, now=now))
+            fresh=fresh, now=now, clouds=clouds))
 
 
 def client_shard_affinity(subscribed: np.ndarray, n_shards: int,

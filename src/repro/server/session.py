@@ -12,9 +12,9 @@ and the whole tick is one jitted dispatch (`_collect_fleet`):
   top-k          = per-client budgeted selection (lax.top_k over the
                    priority-masked scores; invalid rows sort last, so live
                    rows form a prefix exactly like the single-client packet)
-  gather         = fused gather+stride-downsample straight from store rows
-                   to the [C, U, Pc, 3] wire tensor (no [C, U, Pserver, 3]
-                   intermediate)
+  gather         = whole-row gather of the selected slots' client clouds
+                   (`ClientClouds`: each row's stride downsample, computed
+                   once per row write by the zone mirror, not per client)
   sync advance   = vmapped scatter of the shipped versions
 
 Byte accounting matches core/updates.py exactly (same wire format), so the
@@ -64,24 +64,37 @@ class FleetBatch(NamedTuple):
     deleted: jax.Array = None   # [C, U] bool — tombstone rows
 
 
-def _downsample_gather(points: jax.Array, n_points: jax.Array,
-                       idx: jax.Array, row_budget: jax.Array, budget: int):
-    """Gather store rows ``idx`` [C, U] and stride-downsample each row to
-    its own ``row_budget`` (per-class overrides; ``budget`` is the shared
-    buffer width and hard cap) in one fused indexing op — identical
-    semantics to geo.downsample_dyn composed with the row gather, without
-    materializing [C, U, Pserver, 3].
-    """
-    P = points.shape[1]
-    n = jnp.maximum(n_points[idx], 1)                       # [C, U]
-    b = jnp.clip(row_budget, 1, budget)[..., None]          # [C, U, 1]
-    ar = jnp.arange(budget)
-    sub = jnp.where(n[..., None] > b, (ar * n[..., None]) // b, ar)
-    sub = jnp.minimum(sub, P - 1)                           # [C, U, B]
-    out = points[idx[..., None], sub]                       # [C, U, B, 3]
-    n_out = jnp.minimum(n[..., None], b)[..., 0].astype(jnp.int32)
-    valid = ar < n_out[..., None]
-    return jnp.where(valid[..., None], out, 0.0), n_out
+CLOUD_BATCH_ROWS = 256
+
+
+class ClientClouds(NamedTuple):
+    """Every store row's cloud at client resolution, as the collect ships
+    it.  A row's client cloud depends on that row alone (points,
+    ``n_points``, label through ``class_budget_table``), so whoever writes
+    a row writes its client cloud (``ZoneShardedStore``), and the collect
+    gathers whole rows instead of downsampling per (client, row)."""
+    points: jax.Array     # [N, Pc * 3] f16 — one whole row per slot
+    n_points: jax.Array   # [N] int32
+    centroid: jax.Array   # [N, 3] f32 — of the f32 downsample
+
+
+def client_clouds(points: jax.Array, n_points: jax.Array, label: jax.Array,
+                  class_budgets: jax.Array, budget: int) -> ClientClouds:
+    """Stride-downsample each of the R rows ``points`` [R, P, 3] to its
+    class budget (``class_budgets`` [256], ``budget`` the buffer width and
+    hard cap) with geo.downsample_dyn, the centroid taken from the float32
+    downsample before the float16 cast."""
+    def row(x):
+        pts, n, lab = x
+        out, n_out = geo.downsample_dyn(
+            pts, n, class_budgets[jnp.clip(lab, 0, 255)], budget)
+        cent = geo.centroid_bbox(out, n_out)[0]
+        return out.astype(jnp.float16).reshape(budget * 3), n_out, cent
+
+    # in batches of rows: the chip's compiler takes ~1 s for one batch's
+    # gather, ~20 s for one gather over a whole 4,096-slot shard
+    return ClientClouds(*jax.lax.map(row, (points, n_points, label),
+                                     batch_size=CLOUD_BATCH_ROWS))
 
 
 def _changed(store: ObjectStore, synced: jax.Array, ever_sent: jax.Array,
@@ -124,14 +137,17 @@ def _collect_fleet_impl(store: ObjectStore, synced: jax.Array,
                         ever_sent: jax.Array, clear_mask: jax.Array,
                         mask_c: jax.Array,
                         min_obs: jax.Array, user_pos: jax.Array,
-                        interest_embeds, class_budgets: jax.Array, *,
+                        interest_embeds, class_budgets: jax.Array,
+                        clouds: ClientClouds | None = None, *,
                         budget: int, points_budget: int, knobs: Knobs):
     """One update tick for the whole fleet in a single dispatch.
 
     ``class_budgets`` [256] is the per-class client point budget table
     (updates.class_budget_table) — the fleet path honors
     ``Knobs.class_point_overrides`` row-by-row exactly like the
-    single-client gather.
+    single-client gather.  ``clouds`` is the store's client-cloud column
+    (`ClientClouds`, kept by the zone mirror); a caller that holds none
+    gets it built here for the whole store, in this dispatch.
 
     Returns (FleetBatch, new_synced [C, N], new_ever [C, N], nbytes [C],
     counts [C], idx [C, U] — the store slots behind each packet row, for
@@ -150,16 +166,17 @@ def _collect_fleet_impl(store: ObjectStore, synced: jax.Array,
     valid = jnp.isfinite(top)
     row_del = jnp.take_along_axis(tomb, idx, axis=1) & valid  # [C, U]
 
-    row_b = class_budgets[jnp.clip(store.label[idx], 0, 255)]
-    pts, n = _downsample_gather(store.points, store.n_points, idx, row_b,
-                                points_budget)
-    n = jnp.where(row_del, 0, n)
-    pts = jnp.where(row_del[..., None, None], 0.0, pts)
-    cent = jax.vmap(jax.vmap(lambda p, m: geo.centroid_bbox(p, m)[0]))(pts, n)
-    cent = jnp.where(row_del[..., None], store.centroid[idx], cent)
+    if clouds is None:
+        clouds = client_clouds(store.points, store.n_points, store.label,
+                               class_budgets, points_budget)
+    pts = clouds.points[idx].reshape(*idx.shape, points_budget, 3)
+    n = jnp.where(row_del, 0, clouds.n_points[idx])
+    pts = jnp.where(row_del[..., None, None], jnp.float16(0), pts)
+    cent = jnp.where(row_del[..., None], store.centroid[idx],
+                     clouds.centroid[idx])
     batch = FleetBatch(
         oid=store.ids[idx], embed=store.embed[idx], label=store.label[idx],
-        points=pts.astype(jnp.float16), n_points=n, centroid=cent,
+        points=pts, n_points=n, centroid=cent,
         version=store.version[idx], valid=valid, deleted=row_del)
 
     N = synced.shape[1]
@@ -488,14 +505,18 @@ class SessionManager:
                       deliverable: np.ndarray | None = None, zone: int = 0,
                       epoch: np.ndarray | None = None,
                       fresh: np.ndarray | None = None,
-                      now: int | None = None) -> _PendingCollect:
+                      now: int | None = None,
+                      clouds: ClientClouds | None = None) -> _PendingCollect:
         """Issue the fleet collect dispatch; return device handles.
 
         This is the async half of ``collect``: the `_collect_fleet` jit is
         dispatched (donating the old sync state when ``donate``), the sync
         vector is rebound to the new device array, and NO host transfer
         happens — the caller overlaps other dispatch families before
-        ``collect_finish`` materializes counts and does seq bookkeeping."""
+        ``collect_finish`` materializes counts and does seq bookkeeping.
+        ``clouds`` is ``store``'s client-cloud column where the caller
+        keeps one (the zone mirror does); without it the dispatch builds
+        the column for the whole store."""
         mask = self.subscribed if deliverable is None \
             else self.subscribed & np.asarray(deliverable, bool)
         fn = _collect_fleet_donated if self.donate else _collect_fleet
@@ -511,7 +532,8 @@ class SessionManager:
             batch, new_synced, new_ever, nbytes, counts, idx = fn(
                 store, self.sync.synced_version, self.sync.ever_sent,
                 clear, mask_d, min_obs, jnp.asarray(self.user_pos),
-                self.interest_embeds, self._class_budgets, budget=self.budget,
+                self.interest_embeds, self._class_budgets, clouds,
+                budget=self.budget,
                 points_budget=self.knobs.max_object_points_client,
                 knobs=self.knobs)
             sp.fence(batch.valid)
@@ -596,7 +618,8 @@ class SessionManager:
                 deliverable: np.ndarray | None = None, zone: int = 0,
                 epoch: np.ndarray | None = None,
                 fresh: np.ndarray | None = None,
-                now: int | None = None) -> FleetPacket:
+                now: int | None = None,
+                clouds: ClientClouds | None = None) -> FleetPacket:
         """One fleet update tick: ONE jitted dispatch for all C clients.
 
         Every non-empty per-client packet takes the next number on that
@@ -606,4 +629,4 @@ class SessionManager:
         and slot retirement trusts only the latter."""
         return self.collect_finish(self.collect_start(
             store, deliverable=deliverable, zone=zone, epoch=epoch,
-            fresh=fresh, now=now))
+            fresh=fresh, now=now, clouds=clouds))

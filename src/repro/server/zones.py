@@ -15,12 +15,19 @@ jitted scatter per dirty zone, not per object).  Slot bookkeeping is
 host-side; freed shard slots are reported so the per-zone SessionManager
 can forget stale sync versions before the slot is reused.
 
+Each shard carries its client-cloud column (``clouds``, a
+`session.ClientClouds`): every slot's cloud downsampled to client
+resolution.  The scatter that writes a row writes its client cloud, so the
+per-zone collect gathers whole precomputed rows and never reads the
+``[capz, P, 3]`` server cloud.
+
 ``place_on(mesh)`` places the shards round-robin on a mesh's devices
 (`zone_shard_devices`); refreshes then gather the
 changed rows on the global store's device and ship only those.
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field
 
 import numpy as np
@@ -30,8 +37,9 @@ from jax.sharding import Mesh
 
 from repro.core.knobs import Knobs
 from repro.core.store import ObjectStore, deleted_mask, init_store
-from repro.core.updates import _bucket
+from repro.core.updates import _bucket, class_budget_table
 from repro.obs.trace import span as obs_span
+from repro.server.session import ClientClouds, client_clouds
 
 
 @dataclass(frozen=True)
@@ -131,13 +139,22 @@ def _gather_rows(src: ObjectStore, g_idx: jax.Array) -> ObjectStore:
     return jax.tree.map(lambda x: x[g_idx] if x.ndim else x, rows)
 
 
-@jax.jit
-def _zone_scatter(zone: ObjectStore, rows: ObjectStore, z_idx: jax.Array,
-                  valid: jax.Array, deact_idx: jax.Array,
-                  deact_valid: jax.Array) -> ObjectStore:
-    """Write gathered ``rows`` into zone rows z_idx and deactivate
-    deact_idx — one scatter per field, padding rows dropped via OOB
-    indices."""
+@functools.partial(jax.jit, static_argnames=("points_budget",))
+def _zone_clouds(zone: ObjectStore, class_budgets: jax.Array, *,
+                 points_budget: int) -> ClientClouds:
+    """The client-cloud column of every slot of ``zone``."""
+    return client_clouds(zone.points, zone.n_points, zone.label,
+                         class_budgets, points_budget)
+
+
+@functools.partial(jax.jit, static_argnames=("points_budget",))
+def _zone_scatter(zone: ObjectStore, clouds: ClientClouds, rows: ObjectStore,
+                  z_idx: jax.Array, valid: jax.Array, deact_idx: jax.Array,
+                  deact_valid: jax.Array, class_budgets: jax.Array, *,
+                  points_budget: int):
+    """Write gathered ``rows`` into zone rows z_idx, with their client
+    clouds, and deactivate deact_idx — one scatter per field, padding rows
+    dropped via OOB indices.  Returns (zone, clouds)."""
     capz = zone.ids.shape[0]
     tgt = jnp.where(valid, z_idx, capz)
     dt = jnp.where(deact_valid, deact_idx, capz)
@@ -152,7 +169,7 @@ def _zone_scatter(zone: ObjectStore, rows: ObjectStore, z_idx: jax.Array,
                         .at[tgt].set(rows.active, mode="drop")
     deleted = deleted_mask(zone).at[dt].set(False, mode="drop") \
         .at[tgt].set(rows.deleted, mode="drop")
-    return ObjectStore(
+    zone = ObjectStore(
         ids=put(zone.ids, rows.ids), active=active,
         embed=put(zone.embed, rows.embed), label=put(zone.label, rows.label),
         points=put(zone.points, rows.points),
@@ -164,6 +181,11 @@ def _zone_scatter(zone: ObjectStore, rows: ObjectStore, z_idx: jax.Array,
         version=put(zone.version, rows.version),
         last_seen=put(zone.last_seen, rows.last_seen),
         next_id=zone.next_id, deleted=deleted)
+    # freed slots keep their cloud, as the shard keeps their points: they
+    # are not eligible, and a tombstone ships none
+    new = client_clouds(rows.points, rows.n_points, rows.label,
+                        class_budgets, points_budget)
+    return zone, jax.tree.map(put, clouds, new)
 
 
 def _pad_idx(vals: list, bucket: int):
@@ -186,6 +208,9 @@ class ZoneShardedStore:
     indexes: dict = field(default_factory=dict)  # zone -> ClusterIndex
     #                                  (enable_index; core.query discovers
     #                                   this attr for the two-stage plan)
+    clouds: list = field(default_factory=list, init=False)  # per zone:
+    #                                  ClientClouds of the shard's rows,
+    #                                  written with them (_zone_scatter)
     _dropped_oids: set = field(default_factory=set)  # refused by full shard
     _slot: list = field(default_factory=list)   # per zone: {oid -> slot}
     _ver: list = field(default_factory=list)    # per zone: copied version
@@ -203,6 +228,11 @@ class ZoneShardedStore:
                                      self.max_points) for _ in range(Z)]
         else:
             self.zone_capacity = int(self.zones[0].ids.shape[0])
+        self._class_budgets = jnp.asarray(class_budget_table(self.knobs))
+        self.clouds = [_zone_clouds(
+            zone, self._class_budgets,
+            points_budget=self.knobs.max_object_points_client)
+            for zone in self.zones]
         # bookkeeping is rebuilt from the shards' own arrays, so passing
         # pre-populated zones keeps their occupied slots occupied
         self._slot, self._ver, self._free = [], [], []
@@ -278,8 +308,10 @@ class ZoneShardedStore:
                     rows = _gather_rows(store, gb)
                     if self.devices is not None:
                         rows = jax.device_put(rows, self.devices[z])
-                    self.zones[z] = _zone_scatter(self.zones[z], rows, sb, gv,
-                                                  db, dv)
+                    self.zones[z], self.clouds[z] = _zone_scatter(
+                        self.zones[z], self.clouds[z], rows, sb, gv, db, dv,
+                        self._class_budgets,
+                        points_budget=self.knobs.max_object_points_client)
                     # cluster-index maintenance rides the same delta: exactly
                     # the scattered + freed shard slots are re-indexed
                     zidx = self.indexes.get(z)
@@ -287,7 +319,8 @@ class ZoneShardedStore:
                         zidx.update_slots(self.zones[z], s_list + freed)
             if sp.on:
                 sp.set(rows_changed=n_copied,
-                       rows_freed=sum(len(f) for f in freed_per_zone))
+                       rows_freed=sum(len(f) for f in freed_per_zone),
+                       clouds=n_copied)
         return freed_per_zone, changed_per_zone
 
     # ------------------------------------------------------------------
@@ -340,6 +373,8 @@ class ZoneShardedStore:
         self.devices = zone_shard_devices(mesh, len(self.zones))
         self.zones = [jax.device_put(zone, d)
                       for zone, d in zip(self.zones, self.devices)]
+        self.clouds = [jax.device_put(cl, d)
+                       for cl, d in zip(self.clouds, self.devices)]
 
 
 def zone_shard_devices(mesh: Mesh, n_zones: int) -> list:

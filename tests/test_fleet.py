@@ -9,7 +9,8 @@ import jax.numpy as jnp
 from repro.core.knobs import Knobs
 from repro.core.local_map import compute_priority
 from repro.core.runtime import ClientSession, DeviceClient, NetworkModel
-from repro.core.store import synthetic_store
+from repro.core.store import (release_tombstones, remove_objects,
+                              synthetic_store)
 from repro.core.updates import collect_updates, init_sync, update_nbytes
 from repro.server import (FleetServer, SessionManager, ZoneGrid,
                           ZoneShardedStore)
@@ -431,6 +432,183 @@ def test_ack_tick_parity_with_per_client_acks():
         assert all(len(q) == 0 for q in sb.inflight)
     assert np.array_equal(a.epoch_fresh, b.epoch_fresh)
     assert np.array_equal(a.last_ack_tick, b.last_ack_tick)
+
+
+# ---------------------------------------------------------------------------
+# the zone mirror's client-cloud column (server/zones.py, session.py)
+KN_OVR = Knobs(server_capacity=64, client_capacity=64,
+               max_object_points_server=64, max_object_points_client=16,
+               min_obs_before_sync=1,
+               class_point_overrides=((0, 4), (1, 8), (2, 999)))
+GRID2 = ZoneGrid.for_room(8.0, nx=2, nz=1)      # zone 0: x<0, zone 1: x>=0
+
+
+def _ref_client_clouds(zone, kn):
+    """Each shard row stride-downsampled to its class budget the way the
+    collect once did it per (client, row): index i of the output reads
+    floor(i * n / b) when the row has more than b points.  Returns the
+    float16 points [R, Pc, 3], n [R] and the float32 downsample's
+    centroid [R, 3]."""
+    pts = np.asarray(zone.points)
+    n_src, labels = np.asarray(zone.n_points), np.asarray(zone.label)
+    R, P = pts.shape[:2]
+    Pc = kn.max_object_points_client
+    ar = np.arange(Pc)
+    out = np.zeros((R, Pc, 3), np.float32)
+    n_out = np.zeros((R,), np.int32)
+    cent = np.zeros((R, 3), np.float32)
+    for r in range(R):
+        n = max(int(n_src[r]), 1)
+        b = min(max(kn.client_points_for(int(labels[r])), 1), Pc)
+        sub = np.minimum(np.where(n > b, (ar * n) // b, ar), P - 1)
+        k = min(n, b)
+        out[r, :k] = pts[r, sub[:k]]
+        n_out[r] = k
+        cent[r] = out[r, :k].astype(np.float64).mean(axis=0)
+    return out.astype(np.float16), n_out, cent
+
+
+def _churn_stores():
+    """Global stores in turn: inserts, version bumps that change geometry
+    and class, tombstones, their release (shard slots freed), new objects
+    in the released slots (shard slots reused) and a zone crossing."""
+    rng = np.random.default_rng(31)
+    st = synth_store(20, cap=32, seed=21)
+    yield st
+    s = jnp.asarray([1, 4, 7, 12])
+    st = st._replace(points=st.points.at[s].multiply(-0.5),
+                     n_points=st.n_points.at[s].set(
+                         jnp.asarray([3, 64, 17, 40], jnp.int32)),
+                     label=st.label.at[s].set(
+                         jnp.asarray([0, 1, 2, 5], jnp.int32)),
+                     version=st.version.at[s].add(1))
+    yield st
+    ids = np.asarray(st.ids)
+    st = remove_objects(st, ids[[2, 5, 9]])
+    yield st
+    st = release_tombstones(st)
+    yield st
+    s = np.array([2, 5, 9, 20, 21])
+    m = len(s)
+    st = st._replace(
+        ids=st.ids.at[s].set(jnp.arange(101, 101 + m, dtype=jnp.int32)),
+        active=st.active.at[s].set(True),
+        label=st.label.at[s].set(jnp.asarray([0, 1, 3, 2, 0], jnp.int32)),
+        points=st.points.at[s].set(
+            rng.normal(size=(m, 64, 3)).astype(np.float32)),
+        n_points=st.n_points.at[s].set(
+            jnp.asarray(rng.integers(2, 64, size=m), jnp.int32)),
+        centroid=st.centroid.at[s].set(
+            rng.uniform((-4, 0, -4), (4, 2, 4), size=(m, 3))
+            .astype(np.float32)),
+        obs_count=st.obs_count.at[s].set(3),
+        version=st.version.at[s].set(1))
+    yield st
+    c = np.asarray(st.centroid)
+    g = int(np.nonzero(np.asarray(st.active) & (c[:, 0] >= 0.5))[0][0])
+    st = st._replace(centroid=st.centroid.at[g, 0].set(-c[g, 0]),
+                     points=st.points.at[g].add(0.25),
+                     version=st.version.at[g].add(1))
+    yield st
+
+
+def _assert_reference_packet(pkt, idx, zone, kn):
+    """Every row of the packet, padding included, is what the per-row
+    reference downsample of the shard gives at the packet's slots."""
+    ref_pts, ref_n, ref_cent = _ref_client_clouds(zone, kn)
+    b = jax.tree.map(np.asarray, pkt.batch)
+    dele = b.deleted
+    assert not (dele & ~b.valid).any()
+    np.testing.assert_array_equal(b.oid, np.asarray(zone.ids)[idx])
+    np.testing.assert_array_equal(b.version, np.asarray(zone.version)[idx])
+    np.testing.assert_array_equal(
+        dele, np.asarray(zone.deleted)[idx] & b.valid)
+    np.testing.assert_array_equal(b.n_points, np.where(dele, 0, ref_n[idx]))
+    want = np.where(dele[..., None, None], np.float16(0), ref_pts[idx])
+    assert b.points.dtype == np.float16
+    np.testing.assert_array_equal(b.points.view(np.uint16),
+                                  want.view(np.uint16))
+    want_c = np.where(dele[..., None], np.asarray(zone.centroid)[idx],
+                      ref_cent[idx])
+    np.testing.assert_allclose(b.centroid, want_c, rtol=0, atol=1e-6)
+    nb = [sum(update_nbytes(E, int(n), deleted=bool(d))
+              for n, d, v in zip(b.n_points[c], dele[c], b.valid[c]) if v)
+          for c in range(len(pkt.nbytes))]
+    np.testing.assert_array_equal(pkt.nbytes, nb)
+    return int(dele.sum()), int((b.valid & (b.n_points < 16)
+                                 & ~dele).sum())
+
+
+@pytest.mark.parametrize("column", ["maintained", "built"])
+def test_collect_from_client_cloud_column_matches_reference(column):
+    """The collect ships what the per-(client, row) stride downsample
+    shipped, whether it gathers the zone mirror's maintained column or,
+    given none, builds the column itself: oid, version, valid, deleted,
+    n_points, float16 points and bytes bit for bit, centroids within
+    1e-6 m, over inserts, geometry and class changes, tombstones, freed
+    and reused slots and a zone crossing, under class point overrides."""
+    kn = KN_OVR
+    zoned = ZoneShardedStore(knobs=kn, embed_dim=E, grid=GRID2,
+                             zone_capacity=24)
+    poses = np.array([[-2, 1, 0], [2, 1, 1], [0, 1, -3]], np.float32)
+    sessions = [SessionManager(knobs=kn, n_clients=3, capacity=24, budget=8,
+                               user_pos=poses.copy()) for _ in range(2)]
+    tombs = clipped = freed_total = 0
+    for st in _churn_stores():
+        freed, _ = zoned.refresh_from(st)
+        for z, sess in enumerate(sessions):
+            freed_total += len(freed[z])
+            sess.reset_slots(freed[z])
+            for _ in range(2):
+                clouds = zoned.clouds[z] if column == "maintained" else None
+                p = sess.collect_start(zoned.zones[z], zone=z,
+                                       clouds=clouds)
+                pkt = sess.collect_finish(p)
+                t, k = _assert_reference_packet(pkt, np.asarray(p.idx),
+                                                zoned.zones[z], kn)
+                tombs += t
+                clipped += k
+    # the sequence did what it is for: tombstones shipped, slots freed
+    # (release and the zone crossing), rows cut below the buffer width
+    assert tombs > 0 and freed_total >= 4 and clipped > 0
+
+
+@pytest.mark.parametrize("stage", ["refreshes", "prepopulated", "place_on"])
+def test_client_cloud_column_matches_its_shard(stage):
+    """The maintained column equals the column rebuilt from scratch from
+    the shard: after every refresh, for a store built from pre-populated
+    shards, and after place_on and a refresh there."""
+    kn = KN_OVR
+
+    def check(zoned):
+        for zone, cl in zip(zoned.zones, zoned.clouds):
+            pts, n, cent = _ref_client_clouds(zone, kn)
+            np.testing.assert_array_equal(
+                np.asarray(cl.points).reshape(pts.shape).view(np.uint16),
+                pts.view(np.uint16))
+            np.testing.assert_array_equal(np.asarray(cl.n_points), n)
+            np.testing.assert_allclose(np.asarray(cl.centroid), cent,
+                                       rtol=0, atol=1e-6)
+
+    zoned = ZoneShardedStore(knobs=kn, embed_dim=E, grid=GRID2,
+                             zone_capacity=24)
+    for st in _churn_stores():
+        zoned.refresh_from(st)
+        if stage == "refreshes":
+            check(zoned)
+    if stage == "prepopulated":
+        zoned = ZoneShardedStore(knobs=kn, embed_dim=E, grid=GRID2,
+                                 zones=list(zoned.zones))
+        assert zoned.n_active() > 0
+    elif stage == "place_on":
+        from jax.sharding import Mesh
+        zoned.place_on(Mesh(np.array(jax.devices()[:1]), ("d",)))
+        check(zoned)
+        act = np.nonzero(np.asarray(st.active))[0][:3]
+        zoned.refresh_from(st._replace(
+            points=st.points.at[act].multiply(2.0),
+            version=st.version.at[act].add(1)))
+    check(zoned)
 
 
 # ---------------------------------------------------------------------------

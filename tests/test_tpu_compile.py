@@ -126,14 +126,23 @@ def test_ingest_frame_pallas_branch_compiles(one_chip, monkeypatch):
 
 
 def test_collect_fleet_compiles_at_c256(one_chip):
-    """One zone's fleet collect: C=256 clients over a 16,384-slot store at
-    E=512, 2,000 server / 200 client points, 32 rows per client."""
+    """One venue zone's fleet collect: C=256 clients over a 4,096-slot
+    shard at E=512, 2,000 server / 200 client points, 32 rows per client.
+    It gathers whole rows of the shard's client-cloud column, so it takes
+    no [capz, 2000, 3] server cloud and reads a few GB, where the
+    per-(client, row) downsample it replaces read ~111 GB by the same
+    count.  The mirror's scatter that writes the column compiles too."""
     from repro.core.store import init_store
     from repro.core.updates import class_budget_table
-    from repro.server.session import _collect_fleet
+    from repro.server.session import _collect_fleet, _collect_fleet_donated
+    from repro.server.zones import _zone_clouds, _zone_scatter
     kn = Knobs(max_object_points_client=200)
-    C, N, E = 256, 16384, 512
-    store = jax.eval_shape(lambda: init_store(N, E, 2000))
+    C, N, E, P, Pc, B = 256, 4096, 512, 2000, 200, 128
+    store = jax.eval_shape(lambda: init_store(N, E, P))
+    budgets = jax.ShapeDtypeStruct(class_budget_table(kn).shape, jnp.int32)
+    clouds = jax.eval_shape(
+        lambda z, b: _zone_clouds(z, b, points_budget=Pc), store, budgets)
+    assert clouds.points.shape == (N, Pc * 3)
     args = (store,
             jax.ShapeDtypeStruct((C, N), jnp.int32),
             jax.ShapeDtypeStruct((C, N), jnp.bool_),
@@ -141,12 +150,23 @@ def test_collect_fleet_compiles_at_c256(one_chip):
             jax.ShapeDtypeStruct((C,), jnp.bool_),
             jax.ShapeDtypeStruct((C,), jnp.int32),
             jax.ShapeDtypeStruct((C, 3), jnp.float32))
-    budgets = jax.ShapeDtypeStruct(class_budget_table(kn).shape, jnp.int32)
     args = _spec(one_chip, args)
-    c = _collect_fleet.lower(*args, None, _spec(one_chip, budgets),
-                             budget=32, points_budget=200,
-                             knobs=kn).compile()
-    _fits(c)
+    for fn in (_collect_fleet, _collect_fleet_donated):
+        c = fn.lower(*args, None, _spec(one_chip, budgets),
+                     _spec(one_chip, clouds), budget=32, points_budget=Pc,
+                     knobs=kn).compile()
+        assert f"f32[{N},{P},3]" not in c.as_text()
+        cost = c.cost_analysis()
+        cost = cost[0] if isinstance(cost, list) else cost
+        assert cost["bytes accessed"] < 10e9, cost["bytes accessed"]
+        _fits(c)
+    rows = jax.eval_shape(lambda: init_store(B, E, P))
+    idx = jax.ShapeDtypeStruct((B,), jnp.int32)
+    ok = jax.ShapeDtypeStruct((B,), jnp.bool_)
+    s = _zone_scatter.lower(
+        *_spec(one_chip, (store, clouds, rows, idx, ok, idx, ok, budgets)),
+        points_budget=Pc).compile()
+    _fits(s)
 
 
 def test_room_keyframe_ingest_donated_compiles(one_chip, monkeypatch):
